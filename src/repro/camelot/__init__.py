@@ -13,9 +13,10 @@
 #
 # The internal layers (repro.core.*, repro.sim.*, repro.serving.*) remain
 # importable and unchanged; the facade only wires them.
-from repro.camelot.specs import (KNOWN_DEVICES, ClusterSpec, LoadSpec,
-                                 MultiServiceSpec, QoSSpec, ServeSpec,
-                                 ServiceSpec, SolverSpec, TenantSpec)
+from repro.camelot.specs import (DEVICE_KINDS, KNOWN_DEVICES, ClusterSpec,
+                                 LoadSpec, MultiServiceSpec, QoSSpec,
+                                 ServeSpec, ServiceSpec, SolverSpec,
+                                 TenantSpec, device_for_kind)
 from repro.camelot.policies import (BaselinePolicy, MaxPeakPolicy,
                                     MinResourcePolicy, Policy,
                                     UnknownPolicyError, available_policies,
@@ -26,8 +27,9 @@ from repro.core.lifecycle import (AdmissionDecision, AdmissionQuote,
                                   LifecycleEvent, LifecycleManager)
 
 __all__ = [
-    "KNOWN_DEVICES", "ClusterSpec", "LoadSpec", "MultiServiceSpec",
-    "QoSSpec", "ServeSpec", "ServiceSpec", "SolverSpec", "TenantSpec", "BaselinePolicy",
+    "DEVICE_KINDS", "KNOWN_DEVICES", "device_for_kind", "ClusterSpec",
+    "LoadSpec", "MultiServiceSpec", "QoSSpec", "ServeSpec", "ServiceSpec",
+    "SolverSpec", "TenantSpec", "BaselinePolicy",
     "MaxPeakPolicy", "MinResourcePolicy", "Policy", "UnknownPolicyError",
     "available_policies", "get_policy", "register_policy", "CamelotSession",
     "MultiServiceSession", "SAConfig", "SolveResult",

@@ -36,6 +36,21 @@ from repro.core.types import (QUOTA_STEP, RTX_2080TI, TPU_V5E_DEV,
 KNOWN_DEVICES: Dict[str, DeviceSpec] = {
     d.name: d for d in (RTX_2080TI, V100, TPU_V5E_DEV)}
 
+#: the device model for each accelerator JAX can report, keyed by
+#: ``jax.devices()[0].device_kind``; a v5e reports itself as "TPU v5 lite"
+DEVICE_KINDS: Dict[str, DeviceSpec] = {"TPU v5 lite": TPU_V5E_DEV}
+
+
+def device_for_kind(kind: str) -> DeviceSpec:
+    """The ``DeviceSpec`` of the running accelerator.  A kind missing from
+    ``DEVICE_KINDS`` raises: pricing an unknown chip as some other device
+    would mislead every solve made for it."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(f"no device model for device_kind {kind!r}; "
+                         f"known: {sorted(DEVICE_KINDS)}") from None
+
 
 def _chain_edges(n_nodes: int) -> Tuple[ServiceEdge, ...]:
     return tuple(ServiceEdge(i, i + 1) for i in range(n_nodes - 1))

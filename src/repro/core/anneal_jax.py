@@ -21,11 +21,13 @@ Division of labour with the numpy paths:
     state, and hands it to the deterministic greedy ``_polish``.
 
 So the returned allocation is always exact-feasible; jitting only
-accelerates the search.  ``run_anneal`` returns ``None`` whenever the
-kernel cannot run (jax missing, graph past the group-path cap, no
-feasible pool survivor) and ``_anneal`` falls back to the vectorized
-numpy walk — mode "jax" can never produce a result the dense path
-would reject.
+accelerates the search.  ``run_anneal`` returns ``None`` when the
+kernel does not apply (non-linear utility curves, graph past the
+group-path cap) or leaves no exact-feasible survivor, and ``_anneal``
+then takes the vectorized numpy walk — mode "jax" can never produce a
+result the dense path would reject.  A failure of the kernel itself
+raises: on an accelerator it runs on the device, and a fault there
+must not pass for an ordinary fallback.
 
 The jitted program is cached per static shape signature
 (n, walkers, candidates, mutations, grid, group tensors); re-solves at
@@ -39,19 +41,13 @@ import time
 from functools import lru_cache
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.deployment import pack_instances
 from repro.core.incremental import IncrementalEvaluator
 from repro.core.types import Allocation, StageAlloc
-
-try:
-    import jax
-    import jax.numpy as jnp
-    HAVE_JAX = True
-except Exception:                                    # pragma: no cover
-    jax = jnp = None
-    HAVE_JAX = False
 
 
 @lru_cache(maxsize=8)
@@ -165,10 +161,8 @@ def run_anneal(alloc, batch: int, n_devices: int, objective: str,
                warm: Optional[Allocation] = None):
     """Run one jitted annealing walk for ``alloc`` (a CamelotAllocator or
     subclass).  Returns a SolveResult with ``mode="jax"`` or ``None`` when
-    the kernel cannot run — the caller then falls back to the numpy
+    the kernel does not apply — the caller then takes the numpy
     vectorized path."""
-    if not HAVE_JAX:
-        return None
     if getattr(alloc, "_util_codes", None) is not None:
         # non-linear utility curves reshape the max-load objective; the
         # float32 kernel would rank incumbents by the UNtransformed min
@@ -221,32 +215,29 @@ def run_anneal(alloc, batch: int, n_devices: int, objective: str,
     kern = _build_kernel(n, W, c, n_mut, g, Gq, E,
                          bool(sa.bandwidth_constraint),
                          objective == "max_load")
-    try:
-        out = kern(
-            jax.random.PRNGKey(sa.seed & 0x7FFFFFFF),
-            jnp.asarray(NS0, jnp.int32), jnp.asarray(QI0, jnp.int32),
-            jnp.asarray(temps, f32),
-            jnp.asarray(tab.dur, f32), jnp.asarray(tab.bw, f32),
-            jnp.asarray(tab.thpt, f32), jnp.asarray(tab.foots, f32),
-            jnp.asarray(tab.grid, f32), jnp.asarray(norm, f32),
-            jnp.asarray(engine._A, f32), jnp.asarray(engine._B, f32),
-            jnp.asarray(engine._g_nodes, jnp.int32),
-            jnp.asarray(tab.edge_src[ge] if E else ge, jnp.int32),
-            jnp.asarray(tab.edge_dst[ge] if E else ge, jnp.int32),
-            jnp.asarray(tab.edge_t_colo[ge] if E else ge, f32),
-            jnp.asarray(tab.edge_t_host[ge] if E else ge, f32),
-            jnp.asarray(engine._targets, f32),
-            jnp.int32(max_inst),
-            # float32 aggregate sums drift ~1e-4 at thousand-node scale:
-            # admit borderline rows here, let the exact re-eval decide
-            f32(n_devices * 1.0 + 1e-3),
-            jnp.int32(max_inst),
-            f32(n_devices * alloc.device.mem_bandwidth * (1 + 1e-6)),
-            f32(n_devices * alloc.device.mem_capacity * (1 + 1e-6)),
-            f32(required_load if required_load is not None else 0.0))
-        NS_f, QI_f, bNS, bQI, bS, hist = (np.asarray(x) for x in out)
-    except Exception:                                # pragma: no cover
-        return None
+    out = kern(
+        jax.random.PRNGKey(sa.seed & 0x7FFFFFFF),
+        jnp.asarray(NS0, jnp.int32), jnp.asarray(QI0, jnp.int32),
+        jnp.asarray(temps, f32),
+        jnp.asarray(tab.dur, f32), jnp.asarray(tab.bw, f32),
+        jnp.asarray(tab.thpt, f32), jnp.asarray(tab.foots, f32),
+        jnp.asarray(tab.grid, f32), jnp.asarray(norm, f32),
+        jnp.asarray(engine._A, f32), jnp.asarray(engine._B, f32),
+        jnp.asarray(engine._g_nodes, jnp.int32),
+        jnp.asarray(tab.edge_src[ge] if E else ge, jnp.int32),
+        jnp.asarray(tab.edge_dst[ge] if E else ge, jnp.int32),
+        jnp.asarray(tab.edge_t_colo[ge] if E else ge, f32),
+        jnp.asarray(tab.edge_t_host[ge] if E else ge, f32),
+        jnp.asarray(engine._targets, f32),
+        jnp.int32(max_inst),
+        # float32 aggregate sums drift ~1e-4 at thousand-node scale:
+        # admit borderline rows here, let the exact re-eval decide
+        f32(n_devices * 1.0 + 1e-3),
+        jnp.int32(max_inst),
+        f32(n_devices * alloc.device.mem_bandwidth * (1 + 1e-6)),
+        f32(n_devices * alloc.device.mem_capacity * (1 + 1e-6)),
+        f32(required_load if required_load is not None else 0.0))
+    NS_f, QI_f, bNS, bQI, bS, hist = (np.asarray(x) for x in out)
 
     # exact numpy re-evaluation of the incumbent pool (real FFD, float64)
     pool_ns = np.concatenate([bNS, NS_f]).astype(np.int64)

@@ -64,7 +64,7 @@ def _kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     static_argnames=("num_heads", "num_kv_heads", "block_kv", "interpret"))
 def decode_attention_packed(q, k, v, valid, *, num_heads: int,
                             num_kv_heads: int, block_kv: int = 512,
-                            interpret: bool = True):
+                            interpret: bool):
     """q: (B·KVH, G, hd); k, v: (B·KVH, Sc, hd); valid: () int32
     (number of valid cache slots) -> (B·KVH, G, hd)."""
     bkv, g, hd = q.shape

@@ -89,7 +89,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention_bhsd(q, k, v, *, num_heads: int, num_kv_heads: int,
                          causal: bool = True, window: Optional[int] = None,
                          block_q: int = 128, block_kv: int = 128,
-                         interpret: bool = True):
+                         interpret: bool):
     """q: (B·H, Sq, hd); k, v: (B·KVH, Skv, hd) -> (B·H, Sq, hd)."""
     bh, sq, hd = q.shape
     bkv, skv, _ = k.shape

@@ -74,9 +74,21 @@ def _kernel(q_ref, k_ref, v_ref, i_ref, f_ref, c_ref, n_ref, m_ref,
     m_out_ref[0, 0] = m_l
 
 
+def _vmem_limit(l: int, hd: int) -> int:
+    """Scoped VMEM for one (batch, head) program: the whole chunk and both
+    (hd, hd) carries stay resident, double-buffered, plus the (L, L) and
+    (L, hd) f32 intermediates.  At xlstm-1.3b's hd=1024 and L=256 the
+    compiler asks for 24 MiB, past the 16 MiB default; this estimate
+    gives it 33 MiB of the v5e's 128 MiB."""
+    f32 = 4
+    tiles = 2 * f32 * (4 * l * hd + 2 * hd * hd + 2 * hd)
+    temps = f32 * (6 * l * l + 6 * l * hd)
+    return min(max(tiles + temps, 16 << 20), 100 << 20)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def mlstm_chunk_step(q, k, v, i_raw, f_raw, c_in, n_in, m_in, *,
-                     interpret: bool = True):
+                     interpret: bool):
     """q/k/v: (BH, L, hd); i_raw/f_raw: (BH, L); carry c (BH, hd, hd),
     n (BH, hd), m (BH,).  NOTE: k must be pre-scaled by caller's convention?
     No — scale 1/sqrt(hd) is applied inside, matching the model which scales
@@ -113,6 +125,8 @@ def mlstm_chunk_step(q, k, v, i_raw, f_raw, c_in, n_in, m_in, *,
             jax.ShapeDtypeStruct((bh, 1, hd), jnp.float32),
             jax.ShapeDtypeStruct((bh, 1, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(l, hd)),
         interpret=interpret,
     )(q, k, v, i2, f2, c_in, n2, m2)
     return h, c_o, n_o[:, 0], m_o[:, 0, 0]

@@ -4,12 +4,14 @@ Contract (matches repro.models.ssm._chunk_scan): given discretised
 transition da and input dbx, both (B, L, D, ST), compute the inclusive scan
 h_t = da_t * h_{t-1} + dbx_t from h_0 = 0 and return all h_t.
 
-Grid: (B, n_channel_blocks); channels (the ``inner`` dim D) are the
-parallel axis — each program owns a (L, block_d, ST) tile and runs the
-L-step recurrence in VMEM with a fori_loop, carrying (block_d, ST) state.
-Channel blocking keeps the working set = L·block_d·ST·4B inside VMEM
-(e.g. 256·256·16·4 = 6.7 MB) and the lane dim (ST, padded to 128 on real
-hardware) vectorised.
+The recurrence is elementwise over (D, ST), so the wrapper flattens those
+two axes into one lane-dense axis of D·ST: a (D, ST) tile would pad its
+ST=16 lanes out to the 128-lane vreg width and spend 8× the VMEM it holds.
+Grid: (B, n_lane_blocks); each program owns an (L, block_d·ST) tile and
+runs the L-step recurrence in VMEM with a fori_loop, carrying one
+(1, block_d·ST) row of state.  The default ``block_d=128`` keeps the three
+double-buffered tiles at 12 MB for L=256, ST=16 — under the 16 MB default
+scoped VMEM of a v5e.
 """
 from __future__ import annotations
 
@@ -18,25 +20,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(da_ref, dbx_ref, h_ref, carry_ref, *, length: int):
-    carry_ref[...] = jnp.zeros_like(carry_ref)
+def _kernel(da_ref, dbx_ref, h_ref, *, length: int):
+    def body(t, carry):
+        h = da_ref[0, pl.ds(t, 1), :] * carry + dbx_ref[0, pl.ds(t, 1), :]
+        h_ref[0, pl.ds(t, 1), :] = h.astype(h_ref.dtype)
+        return h
 
-    def body(t, _):
-        da_t = da_ref[0, t]                     # (block_d, ST)
-        dbx_t = dbx_ref[0, t]
-        h = da_t * carry_ref[...] + dbx_t
-        carry_ref[...] = h
-        h_ref[0, t] = h.astype(h_ref.dtype)
-        return ()
-
-    jax.lax.fori_loop(0, length, body, ())
+    jax.lax.fori_loop(0, length, body,
+                      jnp.zeros((1, h_ref.shape[-1]), jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
-def ssm_chunk_scan(da, dbx, *, block_d: int = 256, interpret: bool = True):
+def ssm_chunk_scan(da, dbx, *, interpret: bool, block_d: int = 128):
     """da, dbx: (B, L, D, ST) fp32 -> h: (B, L, D, ST) fp32."""
     b, l, d, st = da.shape
     block_d = min(block_d, d)
@@ -45,18 +42,18 @@ def ssm_chunk_scan(da, dbx, *, block_d: int = 256, interpret: bool = True):
         da = jnp.pad(da, ((0, 0), (0, 0), (0, pad), (0, 0)))
         dbx = jnp.pad(dbx, ((0, 0), (0, 0), (0, pad), (0, 0)))
     nd = (d + pad) // block_d
+    lanes = block_d * st
+    flat = (b, l, (d + pad) * st)
 
     out = pl.pallas_call(
         functools.partial(_kernel, length=l),
         grid=(b, nd),
         in_specs=[
-            pl.BlockSpec((1, l, block_d, st), lambda bi, di: (bi, 0, di, 0)),
-            pl.BlockSpec((1, l, block_d, st), lambda bi, di: (bi, 0, di, 0)),
+            pl.BlockSpec((1, l, lanes), lambda bi, di: (bi, 0, di)),
+            pl.BlockSpec((1, l, lanes), lambda bi, di: (bi, 0, di)),
         ],
-        out_specs=pl.BlockSpec((1, l, block_d, st),
-                               lambda bi, di: (bi, 0, di, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, l, d + pad, st), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_d, st), jnp.float32)],
+        out_specs=pl.BlockSpec((1, l, lanes), lambda bi, di: (bi, 0, di)),
+        out_shape=jax.ShapeDtypeStruct(flat, jnp.float32),
         interpret=interpret,
-    )(da, dbx)
-    return out[:, :, :d]
+    )(da.reshape(flat), dbx.reshape(flat))
+    return out.reshape(b, l, d + pad, st)[:, :, :d]

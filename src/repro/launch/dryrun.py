@@ -30,8 +30,8 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, INPUT_SHAPES, TPU_V5E, get_config
 from repro.launch.mesh import make_production_mesh
-from repro.launch.roofline import (analytic_costs, cost_analysis_dict,
-                                   parse_collectives, roofline_terms)
+from repro.launch.roofline import (analytic_costs, parse_collectives,
+                                   roofline_terms)
 from repro.launch.sharding import ShardingRules
 from repro.models import (abstract_cache, abstract_params, decode_cache_len,
                           forward_train, serve_decode, serve_prefill,
@@ -203,7 +203,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool = False,
                            + ma.temp_size_in_bytes
                            - ma.alias_size_in_bytes),
     }
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     rec["cost_analysis_raw"] = {
         "flops": float(ca.get("flops", 0.0)),
         "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

@@ -10,19 +10,12 @@ from __future__ import annotations
 import jax
 
 
-def auto_axis_kwargs(n_axes: int) -> dict:
-    """axis_types only exists on newer jax; older versions default to Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_axis: int = 1):
@@ -31,7 +24,7 @@ def make_host_mesh(model_axis: int = 1):
     assert n % model_axis == 0
     return jax.make_mesh(
         (n // model_axis, model_axis), ("data", "model"),
-        **auto_axis_kwargs(2))
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def data_axes(mesh) -> tuple:

@@ -78,15 +78,6 @@ def _split_computations(hlo: str) -> Dict[str, List[str]]:
     return comps
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """jax version compat: Compiled.cost_analysis() returns one dict on
-    newer jax, a one-element list of dicts on older versions."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
 def parse_collectives(hlo: str) -> dict:
     """Collective byte counts (per device) with while-trip-count roll-up."""
     comps = _split_computations(hlo)

@@ -2,7 +2,8 @@
 
 Builds a pipeline of model-zoo stages, profiles them live, runs the Camelot
 allocator, then serves a batched request trace with the chosen communication
-mechanism.
+mechanism.  The allocator prices the accelerator JAX reports, through
+``repro.camelot.device_for_kind``; a device with no model there is an error.
 
   PYTHONPATH=src python -m repro.launch.serve --stages qwen3-0.6b qwen1.5-0.5b
 """
@@ -10,9 +11,13 @@ from __future__ import annotations
 
 import argparse
 
-from repro.core import (CamelotAllocator, PipelinePredictor, RTX_2080TI,
-                        SAConfig, profile_from_engine)
+import jax
+
+from repro.camelot import device_for_kind
+from repro.core import (CamelotAllocator, PipelinePredictor, SAConfig,
+                        profile_from_engine)
 from repro.core.types import Pipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import ModelStageServer, PipelineEngine, make_trace
 
 
@@ -27,6 +32,8 @@ def main():
     ap.add_argument("--devices", type=int, default=2)
     ap.add_argument("--comm", choices=("device", "host"), default="device")
     args = ap.parse_args()
+    device = device_for_kind(jax.devices()[0].device_kind)
+    enable_compile_cache()
 
     servers = [ModelStageServer(f"stage{i}", arch, seq_len=16, seed=i)
                for i, arch in enumerate(args.stages)]
@@ -35,11 +42,11 @@ def main():
         timings = sv.profile_stage_timings(batches=(1, 2, 4), repeats=2)
         profiles.append(profile_from_engine(
             sv.name, timings, weights_bytes=1e9, act_bytes_per_query=2e7,
-            device=RTX_2080TI, host_bytes_per_query=2e6))
+            device=device, host_bytes_per_query=2e6))
     pipeline = Pipeline("serve", profiles, qos_target=args.qos)
 
-    pred = PipelinePredictor.from_profiles(profiles, RTX_2080TI)
-    alloc = CamelotAllocator(pipeline, pred, RTX_2080TI, args.devices,
+    pred = PipelinePredictor.from_profiles(profiles, device)
+    alloc = CamelotAllocator(pipeline, pred, device, args.devices,
                              sa=SAConfig(iterations=1200, seed=0))
     res = alloc.solve_max_load(args.batch)
     print(f"camelot allocation (predicted {res.objective:.0f} qps): "

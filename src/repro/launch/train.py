@@ -14,6 +14,7 @@ import time
 import jax
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.sharding import ShardingRules
 from repro.models import init_params, set_sharding_rules
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=not args.full_config)
     mesh = (make_production_mesh() if args.production_mesh

@@ -82,20 +82,25 @@ class Query:
 
 
 class ModelStageServer:
-    """One microservice stage: a reduced model served via prefill scoring.
+    """One microservice stage: a model-zoo model served via prefill scoring.
 
-    The stage consumes a token batch (or the previous stage's hidden-state
-    batch re-tokenised via argmax — the pipeline contract used by the
-    Camelot-suite live twins) and emits next-token ids.  ``process`` is
+    ``reduced=True`` (the default) serves the laptop-scale twin of ``arch``
+    (``get_config(arch, reduced=True)``); ``reduced=False`` serves its
+    published configuration.  The stage consumes a token batch (or the
+    previous stage's hidden-state batch re-tokenised via argmax — the
+    pipeline contract used by the Camelot-suite live twins) and emits
+    next-token ids.  ``process`` is
     thread-safe: the engine may run several instances of one stage
     concurrently against the same (immutable) params + jitted callable.
     """
 
-    def __init__(self, name: str, arch: str, seq_len: int = 32, seed: int = 0):
+    def __init__(self, name: str, arch: str, seq_len: int = 32, seed: int = 0,
+                 reduced: bool = True):
         self.name = name
         self._arch = arch
         self._seed = seed
-        self.cfg: ModelConfig = get_config(arch, reduced=True)
+        self.reduced = reduced
+        self.cfg: ModelConfig = get_config(arch, reduced=reduced)
         self.seq_len = seq_len
         self.params = init_params(jax.random.PRNGKey(seed), self.cfg)
         cfg = self.cfg
@@ -123,7 +128,8 @@ class ModelStageServer:
         replica computes exactly what the driver-side original would —
         jitted callables and locks never cross the boundary."""
         return (ModelStageServer,
-                (self.name, self._arch, self.seq_len, self._seed))
+                (self.name, self._arch, self.seq_len, self._seed,
+                 self.reduced))
 
     def warmup(self, batch: int):
         t = jnp.zeros((batch, self.seq_len), jnp.int32)
@@ -166,6 +172,8 @@ class ServeStats:
                                        # past the retry budget, deadline
                                        # abandonment)
     retries: int = 0                   # worker-side retry attempts
+    last_error: Optional[str] = None   # "Type: message" of the latest
+                                       # stage exception
 
     def summary(self) -> dict:
         return {
@@ -178,6 +186,7 @@ class ServeStats:
                          / max(self.comm_time + self.compute_time, 1e-12),
             "failed": self.failed,
             "retries": self.retries,
+            "last_error": self.last_error,
         }
 
 
@@ -485,6 +494,14 @@ class MultiTenantEngine:
             f"unknown backend {backend!r}"
         assert len(tenant_stages) == len(graphs) == len(allocations), \
             "need stages, graph and allocation per tenant"
+        if backend == "processes" and jax.default_backend() == "tpu" and \
+                any(isinstance(st, ModelStageServer)
+                    for stages in tenant_stages for st in stages):
+            raise ValueError(
+                "backend='processes' cannot serve JAX model stages on a "
+                "TPU: one process holds the chip, and this one already "
+                "does, so worker processes could not reach it.  Use "
+                "backend='threads'.")
         self.comm_model = comm_model or CommModel(RTX_2080TI)
         force = None if comm_mechanism == "auto" else comm_mechanism
         self.tenants: List[_TenantServe] = []
@@ -813,6 +830,7 @@ class MultiTenantEngine:
         core = cores[ti]
         core.release(fl.inst, busy_for=dt)
         if err is not None:
+            stats[ti].last_error = err
             fail_or_retry(fl, now)
             return
         if rb.bid in core._abandoned:      # a sibling branch failed
@@ -903,6 +921,7 @@ class MultiTenantEngine:
         core = cores[ti]
         core.release(inst, busy_for=dt)
         if err is not None:
+            stats[ti].last_error = f"{type(err).__name__}: {err}"
             self._fail_or_retry(ti, rb, attempt, core, stats[ti], retry,
                                 time.perf_counter() - start)
             return

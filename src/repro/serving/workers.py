@@ -17,9 +17,9 @@ process boundary:
 This module is imported by spawned children, so it must stay light: numpy
 and the transport layer only (no jax, no solver stack).  Stage servers
 reach workers by pickle — anything picklable works; ``ModelStageServer``
-reconstructs itself from (name, arch, seq_len, seed) via ``__reduce__``,
-and ``CpuStageServer`` below is the picklable CPU-bound stage used by the
-serving benchmarks and tests.
+reconstructs itself from (name, arch, seq_len, seed, reduced) via
+``__reduce__``, and ``CpuStageServer`` below is the picklable CPU-bound
+stage used by the serving benchmarks and tests.
 
 Supervision: ``WorkerSupervisor`` wraps ``repro.core.runtime.HealthMonitor``
 — completions are per-worker heartbeats; a worker whose PROCESS died

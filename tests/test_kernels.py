@@ -1,4 +1,4 @@
-"""Per-kernel correctness: Pallas (interpret=True) vs pure-jnp oracle, with
+"""Per-kernel correctness: Pallas (interpret mode) vs pure-jnp oracle, with
 hypothesis shape/dtype sweeps."""
 import jax
 import jax.numpy as jnp
@@ -72,7 +72,7 @@ def test_flash_attention_block_sizes():
     k = jax.random.normal(ks[1], (2, 100, 16), jnp.float32)
     v = jax.random.normal(ks[2], (2, 100, 16), jnp.float32)
     outs = [flash_attention_bhsd(q, k, v, num_heads=4, num_kv_heads=2,
-                                 block_q=bq, block_kv=bk)
+                                 block_q=bq, block_kv=bk, interpret=True)
             for bq, bk in ((16, 16), (32, 64), (128, 128), (8, 128))]
     for o in outs[1:]:
         _cmp(o, outs[0], "block invariance", atol=1e-4, rtol=1e-4)
@@ -133,9 +133,24 @@ def test_ssm_scan_channel_blocking():
     key = jax.random.PRNGKey(3)
     da = jax.nn.sigmoid(jax.random.normal(key, (2, 16, 100, 8)))
     dbx = jax.random.normal(jax.random.PRNGKey(4), (2, 16, 100, 8))
-    outs = [ssm_chunk_scan(da, dbx, block_d=bd) for bd in (16, 50, 256)]
+    outs = [ssm_chunk_scan(da, dbx, block_d=bd, interpret=True)
+            for bd in (16, 50, 256)]
     for o in outs[1:]:
         _cmp(o, outs[0], "block_d invariance", atol=1e-5, rtol=1e-5)
+
+
+def test_ssm_scan_lane_dense_layout_matches_ref_exactly():
+    """The kernel flattens (D, ST) into one lane axis; the recurrence is
+    the same elementwise f32 arithmetic as the reference, step by step."""
+    from repro.kernels.ref import ssm_chunk_scan_ref
+    from repro.kernels.ssm_scan import ssm_chunk_scan
+    da = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(5),
+                                          (2, 24, 40, 16)))
+    dbx = jax.random.normal(jax.random.PRNGKey(6), (2, 24, 40, 16))
+    for bd in (8, 16, 40):
+        np.testing.assert_array_equal(
+            np.asarray(ssm_chunk_scan(da, dbx, block_d=bd, interpret=True)),
+            np.asarray(ssm_chunk_scan_ref(da, dbx)))
 
 
 # --------------------------------------------------------------------------

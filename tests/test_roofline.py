@@ -7,8 +7,8 @@ import pytest
 
 from repro.configs import INPUT_SHAPES, TPU_V5E, get_config
 from repro.configs.base import InputShape
-from repro.launch.roofline import (analytic_costs, cost_analysis_dict,
-                                   parse_collectives, roofline_terms)
+from repro.launch.roofline import (analytic_costs, parse_collectives,
+                                   roofline_terms)
 
 SYNTHETIC_HLO = """
 HloModule test
@@ -71,7 +71,7 @@ def test_analytic_matches_xla_on_unrolled_smoke():
     # forward only, no remat: 1 layer → while body executes once, so raw
     # cost_analysis is directly comparable to the analytic forward count
     fwd = jax.jit(lambda p, bt: forward_train(p, bt, cfg, remat=False))
-    ca = cost_analysis_dict(fwd.lower(params, batch).compile())
+    ca = fwd.lower(params, batch).compile().cost_analysis()
     xla_flops = float(ca["flops"])
 
     shp = InputShape("smoke", s, b, "prefill")   # prefill == forward pass

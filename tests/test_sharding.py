@@ -77,8 +77,8 @@ import jax, jax.numpy as jnp, json
 from repro.configs import get_config
 from repro.launch.sharding import ShardingRules
 from repro.models import abstract_params, forward_train, set_sharding_rules
-from repro.launch.mesh import auto_axis_kwargs
-mesh = jax.make_mesh((4, 4), ("data", "model"), **auto_axis_kwargs(2))
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_config("qwen3-0.6b", reduced=True)
 rules = ShardingRules(cfg, mesh, "train", 8, 64)
 set_sharding_rules(rules.activation_rules())
@@ -136,7 +136,8 @@ cb.INPUT_SHAPES["tiny_decode"] = InputShape("tiny_decode", 256, 8, "decode")
 dr.INPUT_SHAPES = cb.INPUT_SHAPES
 import repro.launch.mesh as lm
 lm.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
-    (4, 4), ("data", "model"), **lm.auto_axis_kwargs(2))
+    (4, 4), ("data", "model"),
+    axis_types=(jax.sharding.AxisType.Auto,) * 2)
 dr.make_production_mesh = lm.make_production_mesh
 rec = dr.run_combo("qwen3-0.6b", "tiny_decode")
 print(json.dumps({"status": rec["status"],

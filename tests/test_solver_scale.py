@@ -135,9 +135,6 @@ def test_hierarchical_multi_pod_feasible_and_partitioned():
 # --------------------------------------------------------------------------
 
 def test_jax_kernel_within_tolerance_on_every_suite_workload():
-    anneal_jax = pytest.importorskip("repro.core.anneal_jax")
-    if not anneal_jax.HAVE_JAX:
-        pytest.skip("jax not available")
     for name, tenants in multitenant_suite().items():
         ts = TenantSet(tenants)
         pred = PipelinePredictor.from_graph(ts.union_graph, RTX_2080TI,
