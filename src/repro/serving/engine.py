@@ -35,6 +35,17 @@ Two execution backends share this driver (``backend=`` knob):
     is detected, restarted, and its in-flight batches replayed within
     the retry budget (``WorkerSupervisor``).
 
+Both drivers write ``camelot.*`` spans into the JAX profiler's trace
+(``jax.profiler.TraceAnnotation``; next to free while no trace runs):
+one per phase of the driver loop that did work (``admit``, ``form``,
+``dispatch``, ``complete``, ``handoff``), one per wait for a completion
+named by what set its timeout (``wait_arrival``, ``wait_batch``,
+``wait_retry``, ``wait_completion``), one per worker task (``worker``)
+and, inside ``ModelStageServer.process``, one per step (``step``).  Each
+carries the tenant, the node's name and the batch id where it has them.
+``ServeStats`` sums the waits those spans show: batch formation, ready
+queues, completion pick-up and the hand-off.
+
 Retry backoff is driver-scheduled on BOTH backends: a failing batch is
 requeued with a timed wake (``retry_backoff × 2^attempt``) instead of
 sleeping inside a worker slot, so a backing-off batch never idles an
@@ -60,6 +71,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import ModelConfig, get_config
 from repro.core.comm import (GLOBAL_MEMORY, HOST_STAGED, CommModel,
@@ -118,9 +130,6 @@ class ModelStageServer:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         self._run = jax.jit(run)
-        self._stats_lock = threading.Lock()
-        self.calls = 0
-        self.busy_time = 0.0
 
     def __reduce__(self):
         """Rebuild from construction arguments across process boundaries:
@@ -136,13 +145,11 @@ class ModelStageServer:
         self._run(self.params, t).block_until_ready()
 
     def process(self, tokens: jax.Array) -> jax.Array:
-        t0 = time.perf_counter()
-        out = self._run(self.params, tokens)
-        out.block_until_ready()
-        dt = time.perf_counter() - t0
-        with self._stats_lock:
-            self.busy_time += dt
-            self.calls += 1
+        # from the dispatch of the step program to its result being ready:
+        # the program's device time plus what it waited for the device
+        with TraceAnnotation("camelot.step", stage=self.name):
+            out = self._run(self.params, tokens)
+            out.block_until_ready()
         return out
 
     def profile_stage_timings(self, batches: Sequence[int] = (1, 2, 4, 8),
@@ -164,9 +171,27 @@ class ModelStageServer:
 
 @dataclass
 class ServeStats:
+    """What one ``run_traces`` call served, per tenant.  The waits are
+    sums in seconds, each with the count it is summed over beside it:
+
+    * ``batch_wait_s`` / ``batched_queries``: from a query's scheduled
+      arrival to the formation of its entry batch;
+    * ``ready_wait_s`` / ``dispatches``: from a batch being ready at a
+      stage (formed, or handed off to it) to its dispatch to an instance;
+    * ``complete_lag_s`` / ``folds``: from a worker posting a stage call's
+      completion to the driver starting to fold it in;
+    * ``handoff_s``: the driver's whole hand-off to the successors of a
+      finished stage (channel send, join delivery, fan-in combine)."""
     qos: QoSTracker
     comm_time: float = 0.0
     compute_time: float = 0.0
+    batch_wait_s: float = 0.0
+    batched_queries: int = 0
+    ready_wait_s: float = 0.0
+    dispatches: int = 0
+    complete_lag_s: float = 0.0
+    folds: int = 0
+    handoff_s: float = 0.0
     batches: int = 0
     failed: int = 0                    # queries lost (worker exceptions
                                        # past the retry budget, deadline
@@ -184,6 +209,13 @@ class ServeStats:
             "compute_time": self.compute_time,
             "comm_frac": self.comm_time
                          / max(self.comm_time + self.compute_time, 1e-12),
+            "batch_wait_s": self.batch_wait_s,
+            "batched_queries": self.batched_queries,
+            "ready_wait_s": self.ready_wait_s,
+            "dispatches": self.dispatches,
+            "complete_lag_s": self.complete_lag_s,
+            "folds": self.folds,
+            "handoff_s": self.handoff_s,
             "failed": self.failed,
             "retries": self.retries,
             "last_error": self.last_error,
@@ -384,6 +416,14 @@ class _TenantServe:
     alloc: Allocation
     channels: _EdgeChannels
     batch_size: int
+    names: List[str] = field(init=False)   # per node, for spans
+
+    def __post_init__(self):
+        # the graph node's name, else the stage server's, else its index
+        self.names = [getattr(node, "name", None)
+                      or getattr(st, "name", None) or str(i)
+                      for i, (node, st) in enumerate(zip(self.graph.nodes,
+                                                         self.stages))]
 
 
 class _RetryQueue:
@@ -604,46 +644,27 @@ class MultiTenantEngine:
         start = time.perf_counter()
         total_inst = sum(len(c.instances) for c in cores)
         with ThreadPoolExecutor(max_workers=max(total_inst, 1)) as ex:
+
+            def submit(ti: int, inst: StageInstance, rb: ReadyBatch,
+                       attempt: int) -> None:
+                nonlocal in_flight
+                in_flight += 1
+                ex.submit(self._worker, ti, inst, rb, completions, attempt)
+
             while any(i < n for i, n in zip(idx, lens)) or in_flight \
                     or retry or any(c.has_work() for c in cores):
                 now = time.perf_counter() - start
                 self._apply_pending(cores, ex)
                 self._requeue_due(retry, cores, now)
-                for ti, (t, core, tr) in enumerate(
-                        zip(self.tenants, cores, traces)):
-                    while idx[ti] < lens[ti] and \
-                            tr[idx[ti]].arrival <= now:
-                        core.admit(tr[idx[ti]], tr[idx[ti]].arrival)
-                        idx[ti] += 1
-                    if self.deadline is not None and core.pending:
-                        # per-query deadline: abandon arrivals that have
-                        # already waited past it instead of batching them
-                        keep = [(a, q) for a, q in core.pending
-                                if now - a <= self.deadline]
-                        n_drop = len(core.pending) - len(keep)
-                        if n_drop:
-                            core.pending = keep
-                            stats[ti].failed += n_drop
-                    for rb in core.form_batches(now):
-                        rb.data = _stack_tokens(
-                            [q.tokens for q in rb.items], t.batch_size)
-                    for inst, rb in core.dispatch(now):
-                        in_flight += 1
-                        ex.submit(self._worker, ti, inst, rb, completions,
-                                  retry.take(ti, rb))
-                # sleep until the next event across ALL tenants
-                wake = [traces[ti][idx[ti]].arrival
-                        for ti in range(len(self.tenants))
-                        if idx[ti] < lens[ti]]
-                wake += [d for d in (c.batch_deadline() for c in cores)
-                         if d is not None]
-                rw = retry.next_wake()
-                if rw is not None:
-                    wake.append(rw)
-                timeout = (min(wake) - now) if wake else 0.05
-                timeout = min(max(timeout, 0.0005), 0.05)
+                for ti in range(len(self.tenants)):
+                    self._schedule(ti, cores[ti], traces[ti], idx, now,
+                                   start, stats[ti], retry, _stack_tokens,
+                                   submit)
+                timeout, wait = self._next_wake(traces, idx, cores, retry,
+                                                now)
                 try:
-                    ev = completions.get(timeout=timeout)
+                    with TraceAnnotation(wait):
+                        ev = completions.get(timeout=timeout)
                 except queue.Empty:
                     continue
                 while True:
@@ -734,6 +755,19 @@ class MultiTenantEngine:
             unpin(fl.input_refs)
             drop_bid(fl.ti, fl.rb.bid)
 
+        def submit(ti: int, inst: StageInstance, rb: ReadyBatch,
+                   attempt: int) -> None:
+            fid = next(fid_gen)
+            refs = [v for v in (rb.inputs or {}).values()
+                    if isinstance(v, PayloadRef)]
+            inflight[fid] = _InFlight(ti, inst, rb, attempt, inst.device,
+                                      refs)
+            if rb.inputs is not None:
+                task = (fid, ti, rb.stage, None, dict(rb.inputs), attempt)
+            else:
+                task = (fid, ti, rb.stage, rb.data, None, attempt)
+            pool.submit(inst.device, task)
+
         idx = [0] * len(self.tenants)
         lens = [len(tr) for tr in traces]
         # workers (re-)tracked per run: supervisor heartbeats are
@@ -757,47 +791,14 @@ class MultiTenantEngine:
                     cores[fl.ti].release(fl.inst, busy_for=0.0)
                     fail_or_retry(fl, now)
             self._requeue_due(retry, cores, now)
-            for ti, (t, core, tr) in enumerate(
-                    zip(self.tenants, cores, traces)):
-                while idx[ti] < lens[ti] and tr[idx[ti]].arrival <= now:
-                    core.admit(tr[idx[ti]], tr[idx[ti]].arrival)
-                    idx[ti] += 1
-                if self.deadline is not None and core.pending:
-                    keep = [(a, q) for a, q in core.pending
-                            if now - a <= self.deadline]
-                    n_drop = len(core.pending) - len(keep)
-                    if n_drop:
-                        core.pending = keep
-                        stats[ti].failed += n_drop
-                for rb in core.form_batches(now):
-                    # host-resident stacking: workers are jax-free
-                    rb.data = _stack_tokens_np(
-                        [q.tokens for q in rb.items], t.batch_size)
-                for inst, rb in core.dispatch(now):
-                    fid = next(fid_gen)
-                    refs = [v for v in (rb.inputs or {}).values()
-                            if isinstance(v, PayloadRef)]
-                    inflight[fid] = _InFlight(ti, inst, rb,
-                                              retry.take(ti, rb),
-                                              inst.device, refs)
-                    if rb.inputs is not None:
-                        task = (fid, ti, rb.stage, None, dict(rb.inputs),
-                                inflight[fid].attempt)
-                    else:
-                        task = (fid, ti, rb.stage, rb.data, None,
-                                inflight[fid].attempt)
-                    pool.submit(inst.device, task)
-            wake = [traces[ti][idx[ti]].arrival
-                    for ti in range(len(self.tenants))
-                    if idx[ti] < lens[ti]]
-            wake += [d for d in (c.batch_deadline() for c in cores)
-                     if d is not None]
-            rw = retry.next_wake()
-            if rw is not None:
-                wake.append(rw)
-            timeout = (min(wake) - now) if wake else 0.05
-            timeout = min(max(timeout, 0.0005), 0.05)
-            for ev in pool.poll(timeout):
+            for ti in range(len(self.tenants)):
+                # host-resident stacking: workers are jax-free
+                self._schedule(ti, cores[ti], traces[ti], idx, now, start,
+                               stats[ti], retry, _stack_tokens_np, submit)
+            timeout, wait = self._next_wake(traces, idx, cores, retry, now)
+            with TraceAnnotation(wait):
+                done = pool.poll(timeout)
+            for ev in done:
                 self._complete_proc(ev, cores, stats, start, retry,
                                     inflight, pins, bid_refs, unpin,
                                     drop_bid, fail_or_retry)
@@ -815,8 +816,9 @@ class MultiTenantEngine:
         ``EdgeChannel`` so per-edge stats read identically across
         backends)."""
         pool, sup = self._pool, self._supervisor
-        wid, fid, payload, dt, err, mech, nbytes, t_comm = ev
-        now = time.perf_counter() - start
+        wid, fid, payload, dt, err, mech, nbytes, t_comm, t_put = ev
+        t_fold = time.perf_counter()
+        now = t_fold - start
         sup.beat(wid, now)
         fl = inflight.pop(fid, None)
         if fl is None:
@@ -828,40 +830,51 @@ class MultiTenantEngine:
         ti, rb = fl.ti, fl.rb
         t = self.tenants[ti]
         core = cores[ti]
-        core.release(fl.inst, busy_for=dt)
-        if err is not None:
-            stats[ti].last_error = err
-            fail_or_retry(fl, now)
-            return
-        if rb.bid in core._abandoned:      # a sibling branch failed
-            if isinstance(payload, PayloadRef):
-                pool.free(payload)
-            return
-        stats[ti].compute_time += dt
-        stats[ti].comm_time += t_comm
-        # this batch is terminal for its inputs: release their slot pins
-        unpin(fl.input_refs)
-        u = rb.stage
-        succs = core.succs[u]
-        if succs:
-            if isinstance(payload, PayloadRef):
-                pins[payload.key()] = [len(succs), payload]
-                bid_refs.setdefault((ti, rb.bid), set()).add(payload.key())
-            mech_name = GLOBAL_MEMORY if mech == SHM else HOST_STAGED
-            for v in succs:
-                t.channels[(u, v)].record(mech_name, nbytes)
-                # joined batches keep raw inputs: the CONSUMER's worker
-                # resolves refs and runs the fan-in combine process-side
-                core.deliver(u, v, rb.bid, rb.items, now, data=payload)
-        else:
-            if isinstance(payload, PayloadRef):
-                pool.free(payload)
-            if core.complete_exit(rb.bid, u):
-                for q in rb.items:
-                    q.done = now
-                    stats[ti].qos.record(now - q.arrival)
-                stats[ti].batches += 1
-                drop_bid(ti, rb.bid)
+        stats[ti].complete_lag_s += t_fold - t_put
+        stats[ti].folds += 1
+        with TraceAnnotation("camelot.complete", tenant=ti,
+                             stage=t.names[rb.stage], bid=rb.bid):
+            core.release(fl.inst, busy_for=dt)
+            if err is not None:
+                stats[ti].last_error = err
+                fail_or_retry(fl, now)
+                return
+            if rb.bid in core._abandoned:      # a sibling branch failed
+                if isinstance(payload, PayloadRef):
+                    pool.free(payload)
+                return
+            stats[ti].compute_time += dt
+            stats[ti].comm_time += t_comm
+            # this batch is terminal for its inputs: release their slot pins
+            unpin(fl.input_refs)
+            u = rb.stage
+            succs = core.succs[u]
+            if succs:
+                if isinstance(payload, PayloadRef):
+                    pins[payload.key()] = [len(succs), payload]
+                    bid_refs.setdefault((ti, rb.bid),
+                                        set()).add(payload.key())
+                mech_name = GLOBAL_MEMORY if mech == SHM else HOST_STAGED
+                for v in succs:
+                    with TraceAnnotation("camelot.handoff", tenant=ti,
+                                         stage=t.names[u], bid=rb.bid):
+                        t0 = time.perf_counter()
+                        t.channels[(u, v)].record(mech_name, nbytes)
+                        # joined batches keep raw inputs: the CONSUMER's
+                        # worker resolves refs and runs the fan-in combine
+                        # process-side
+                        joined = core.deliver(u, v, rb.bid, rb.items, now,
+                                              data=payload)
+                        self._handed_off(joined, stats[ti], t0, start)
+            else:
+                if isinstance(payload, PayloadRef):
+                    pool.free(payload)
+                if core.complete_exit(rb.bid, u):
+                    for q in rb.items:
+                        q.done = now
+                        stats[ti].qos.record(now - q.arrival)
+                    stats[ti].batches += 1
+                    drop_bid(ti, rb.bid)
 
     # ---- internals -----------------------------------------------------
 
@@ -871,14 +884,93 @@ class MultiTenantEngine:
         driver as timed requeues (``_RetryQueue``) — the pre-fix behaviour
         slept the backoff out right here, pinning the worker slot (and the
         stage instance holding it) idle for the whole backoff window."""
-        t0 = time.perf_counter()
-        try:
-            out, err = \
-                self.tenants[ti].stages[inst.stage].process(rb.data), None
-        except BaseException as e:
-            out, err = None, e
-        completions.put((ti, inst, rb, out, time.perf_counter() - t0, err,
-                         attempt))
+        t = self.tenants[ti]
+        with TraceAnnotation("camelot.worker", tenant=ti,
+                             stage=t.names[inst.stage], bid=rb.bid,
+                             attempt=attempt):
+            t0 = time.perf_counter()
+            try:
+                out, err = t.stages[inst.stage].process(rb.data), None
+            except BaseException as e:
+                out, err = None, e
+            t_put = time.perf_counter()
+            completions.put((ti, inst, rb, out, t_put - t0, err, attempt,
+                             t_put))
+
+    def _schedule(self, ti: int, core: ExecCore, trace: List[Query],
+                  idx: List[int], now: float, start: float,
+                  stats: ServeStats, retry: "_RetryQueue", stack,
+                  submit) -> None:
+        """One tenant's admission, deadline shedding, batch formation and
+        dispatch at ``now`` — the step both backends' drivers share.
+        ``stack`` builds an entry batch's input from its prompts;
+        ``submit(ti, inst, rb, attempt)`` hands a dispatched batch to the
+        backend."""
+        t = self.tenants[ti]
+        i, n = idx[ti], len(trace)
+        if i < n and trace[i].arrival <= now:
+            with TraceAnnotation("camelot.admit", tenant=ti):
+                while i < n and trace[i].arrival <= now:
+                    core.admit(trace[i], trace[i].arrival)
+                    i += 1
+            idx[ti] = i
+        if self.deadline is not None and core.pending:
+            # per-query deadline: abandon arrivals that have already
+            # waited past it instead of batching them
+            keep = [(a, q) for a, q in core.pending
+                    if now - a <= self.deadline]
+            n_drop = len(core.pending) - len(keep)
+            if n_drop:
+                core.pending = keep
+                stats.failed += n_drop
+        for rb in core.form_batches(now):
+            with TraceAnnotation("camelot.form", tenant=ti,
+                                 stage=t.names[rb.stage], bid=rb.bid):
+                rb.data = stack([q.tokens for q in rb.items], t.batch_size)
+            if rb.stage == core.entries[0]:    # once per admitted query
+                stats.batch_wait_s += sum(now - q.arrival for q in rb.items)
+                stats.batched_queries += len(rb.items)
+        for inst, rb in core.dispatch(now):
+            stats.ready_wait_s += time.perf_counter() - start - rb.ready_time
+            stats.dispatches += 1
+            with TraceAnnotation("camelot.dispatch", tenant=ti,
+                                 stage=t.names[rb.stage], bid=rb.bid):
+                submit(ti, inst, rb, retry.take(ti, rb))
+
+    @staticmethod
+    def _next_wake(traces: Sequence[List[Query]], idx: List[int],
+                   cores: List[ExecCore], retry: "_RetryQueue",
+                   now: float) -> Tuple[float, str]:
+        """How long the driver may wait for a completion before the next
+        event it must act on across ALL tenants (an arrival, a batch
+        deadline, a retry's wake), and the name of the span that wait is
+        recorded under: what set the timeout, or ``wait_completion`` when
+        nothing is due and only in-flight work remains."""
+        wake, name = None, "camelot.wait_completion"
+        due = [(tr[i].arrival, "camelot.wait_arrival")
+               for tr, i in zip(traces, idx) if i < len(tr)]
+        due += [(d, "camelot.wait_batch")
+                for d in (c.batch_deadline() for c in cores)
+                if d is not None]
+        rw = retry.next_wake()
+        if rw is not None:
+            due.append((rw, "camelot.wait_retry"))
+        for at, why in due:
+            if wake is None or at < wake:
+                wake, name = at, why
+        timeout = (wake - now) if wake is not None else 0.05
+        return min(max(timeout, 0.0005), 0.05), name
+
+    @staticmethod
+    def _handed_off(joined: Optional[ReadyBatch], stats: ServeStats,
+                    t0: float, start: float) -> None:
+        """Count a hand-off that began at ``t0``.  A batch it made ready
+        counts as ready from the hand-off's end, so that its ready wait
+        and the hand-off do not overlap."""
+        t1 = time.perf_counter()
+        stats.handoff_s += t1 - t0
+        if joined is not None:
+            joined.ready_time = t1 - start
 
     def _fail_or_retry(self, ti: int, rb: ReadyBatch, attempt: int,
                        core: ExecCore, stats: ServeStats,
@@ -916,31 +1008,40 @@ class MultiTenantEngine:
     def _complete(self, ev, cores: List[ExecCore],
                   stats: List[ServeStats], start: float,
                   retry: "_RetryQueue") -> None:
-        ti, inst, rb, out, dt, err, attempt = ev
+        ti, inst, rb, out, dt, err, attempt, t_put = ev
+        stats[ti].complete_lag_s += time.perf_counter() - t_put
+        stats[ti].folds += 1
         t = self.tenants[ti]
         core = cores[ti]
-        core.release(inst, busy_for=dt)
-        if err is not None:
-            stats[ti].last_error = f"{type(err).__name__}: {err}"
-            self._fail_or_retry(ti, rb, attempt, core, stats[ti], retry,
-                                time.perf_counter() - start)
-            return
-        stats[ti].compute_time += dt
-        u = rb.stage
-        now = time.perf_counter() - start
-        succs = core.succs[u]
-        if succs:
-            for v in succs:
-                same = inst.device in core.consumer_devices(v)
-                t0 = time.perf_counter()
-                handed = t.channels[(u, v)].send(out, same_device=same)
-                stats[ti].comm_time += time.perf_counter() - t0
-                joined = core.deliver(u, v, rb.bid, rb.items, now,
-                                      data=handed)
-                if joined is not None:
-                    joined.data = _fanin_combine(t.stages, v, joined.inputs)
-        elif core.complete_exit(rb.bid, u):
-            for q in rb.items:
-                q.done = now
-                stats[ti].qos.record(now - q.arrival)
-            stats[ti].batches += 1
+        with TraceAnnotation("camelot.complete", tenant=ti,
+                             stage=t.names[rb.stage], bid=rb.bid):
+            core.release(inst, busy_for=dt)
+            if err is not None:
+                stats[ti].last_error = f"{type(err).__name__}: {err}"
+                self._fail_or_retry(ti, rb, attempt, core, stats[ti], retry,
+                                    time.perf_counter() - start)
+                return
+            stats[ti].compute_time += dt
+            u = rb.stage
+            now = time.perf_counter() - start
+            succs = core.succs[u]
+            if succs:
+                for v in succs:
+                    with TraceAnnotation("camelot.handoff", tenant=ti,
+                                         stage=t.names[u], bid=rb.bid):
+                        same = inst.device in core.consumer_devices(v)
+                        t0 = time.perf_counter()
+                        handed = t.channels[(u, v)].send(out,
+                                                         same_device=same)
+                        stats[ti].comm_time += time.perf_counter() - t0
+                        joined = core.deliver(u, v, rb.bid, rb.items, now,
+                                              data=handed)
+                        if joined is not None:
+                            joined.data = _fanin_combine(t.stages, v,
+                                                         joined.inputs)
+                        self._handed_off(joined, stats[ti], t0, start)
+            elif core.complete_exit(rb.bid, u):
+                for q in rb.items:
+                    q.done = now
+                    stats[ti].qos.record(now - q.arrival)
+                stats[ti].batches += 1

@@ -48,10 +48,12 @@ __all__ = ["CpuStageServer", "WorkerPool", "WorkerSupervisor",
 
 #: task tuple: (fid, tenant, stage, data, inputs, attempt)
 WorkerTask = Tuple[int, int, int, object, Optional[dict], int]
-#: completion tuple:
-#: (worker, fid, payload, compute_s, err, mechanism, nbytes, comm_s)
+#: completion tuple: (worker, fid, payload, compute_s, err, mechanism,
+#: nbytes, comm_s, put_at); ``put_at`` is the worker's ``perf_counter`` as
+#: it posts the completion (a system-wide clock, so the driver can read
+#: how long the completion waited for it)
 WorkerDone = Tuple[int, int, object, float, Optional[str], Optional[str],
-                   int, float]
+                   int, float, float]
 
 
 class CpuStageServer:
@@ -154,7 +156,7 @@ def _worker_main(wid: int, task_q, done_q, stages_blob: bytes,
         b = cfg.batch_sizes[ti] if ti < len(cfg.batch_sizes) else 1
         for st in stages:
             st.warmup(b)
-    done_q.put((wid, -1, None, 0.0, None, None, 0, 0.0))   # ready beacon
+    done_q.put((wid, -1, None, 0.0, None, None, 0, 0.0, 0.0))  # ready beacon
     while True:
         task = task_q.get()
         if task is None:
@@ -182,12 +184,14 @@ def _worker_main(wid: int, task_q, done_q, stages_blob: bytes,
                     mech = QUEUE
                 else:
                     payload = ref
-            t_comm += time.perf_counter() - tc0
+            t_put = time.perf_counter()
+            t_comm += t_put - tc0
             done_q.put((wid, fid, payload, dt, None, mech, int(out.nbytes),
-                        t_comm))
+                        t_comm, t_put))
         except BaseException as e:  # noqa: BLE001 — report, never die
-            done_q.put((wid, fid, None, time.perf_counter() - t0,
-                        f"{type(e).__name__}: {e}", None, 0, t_comm))
+            t_put = time.perf_counter()
+            done_q.put((wid, fid, None, t_put - t0,
+                        f"{type(e).__name__}: {e}", None, 0, t_comm, t_put))
     arena.close()
     amap.close()
 
