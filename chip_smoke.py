@@ -159,7 +159,8 @@ def run_smoke(device: DeviceSpec, *, reduced: bool, seq_len: int = 128,
             f"{m['param_bytes']} B on {','.join(m['devices'])}; init "
             f"{init_s:.3f} s; warm-up+compile {m['warmup_s']:.3f} s; "
             f"median step {m['median_step_s'] * 1e3:.3f} ms at batch "
-            f"{batch} x seq {seq_len}; logits vs host CPU rel err {err:.3g}")
+            f"{batch} x seq {seq_len}; attention {stage.attn_path}; logits "
+            f"vs host CPU rel err {err:.3g}")
         stages.append(stage)
         resident += m["param_bytes"]
     log(f"params resident: {resident} B")

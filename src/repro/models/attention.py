@@ -1,26 +1,41 @@
 """Attention: GQA/MQA/MHA, RoPE, qk-norm, sliding window, KV-cache decode.
 
-Three execution paths:
+Execution paths:
+- ``prefill_attention`` — the Pallas TPU kernel of
+                        repro.kernels.prefill_attention: qk-norm, RoPE and
+                        grouped attention of a prompt in one VMEM block,
+                        on q/k/v as the projections write them.  Prefill on
+                        a TPU, where the prompt fits (``attn_path``).
 - ``flash_attn``      — chunked online-softmax attention (pure-XLA scan over
                         KV blocks; bounded transients at 32k prefill).  Used by
-                        train/prefill.  A Pallas TPU kernel implementing the
-                        same contract lives in repro.kernels.flash_attention.
+                        train, and by prefill elsewhere.  A Pallas TPU kernel
+                        implementing the same contract lives in
+                        repro.kernels.flash_attention.
 - ``decode_attn``     — one-token attention over a (possibly ring-buffer)
                         KV cache.  Pallas twin: repro.kernels.decode_attention.
-- naive reference     — in repro.kernels.ref (oracle for both).
+- naive reference     — in repro.kernels.ref (oracle for all).
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import threading
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import prefill_attention as prefill_kernel
 from repro.models.common import (apply_rope, constrain, dense_init,
-                                 head_rms_norm)
+                                 get_sharding_rules, head_rms_norm,
+                                 rope_frequencies)
 
 NEG_INF = -1e30
+KERNEL_PATH = "prefill_attention"
+FLASH_PATH = "flash_attn"
+
+_paths = threading.local()
 
 
 class KVCache(NamedTuple):
@@ -65,25 +80,36 @@ def init_attn_params(key, cfg: ModelConfig, dtype=jnp.bfloat16,
     return p
 
 
-def project_qkv(x: jax.Array, p: dict, cfg: ModelConfig,
-                positions: Optional[jax.Array]):
-    """x: (B, S, d) -> q (B,S,H,hd), k/v (B,S,KVH,hd); RoPE applied."""
-    b, s, _ = x.shape
-    hd = cfg.resolved_head_dim
+def _project(x: jax.Array, p: dict, cfg: ModelConfig):
+    """x: (B, S, d) -> q (B,S,H·hd), k/v (B,S,KVH·hd), bias added."""
     q = jnp.einsum("bsd,dk->bsk", x, p["wq"])
     k = jnp.einsum("bsd,dk->bsk", x, p["wk"])
     v = jnp.einsum("bsd,dk->bsk", x, p["wv"])
     if cfg.qkv_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = q.reshape(b, s, cfg.num_heads, hd)
-    k = k.reshape(b, s, cfg.num_kv_heads, hd)
-    v = v.reshape(b, s, cfg.num_kv_heads, hd)
+    return q, k, v
+
+
+def _heads(x: jax.Array, heads: int, norm: Optional[jax.Array],
+           cfg: ModelConfig, positions: Optional[jax.Array]) -> jax.Array:
+    """(B, S, heads·hd) -> (B, S, heads, hd); qk-norm and RoPE applied."""
+    b, s, _ = x.shape
+    x = x.reshape(b, s, heads, cfg.resolved_head_dim)
     if cfg.qk_norm:
-        q = head_rms_norm(q, p["q_norm"], cfg.norm_eps)
-        k = head_rms_norm(k, p["k_norm"], cfg.norm_eps)
+        x = head_rms_norm(x, norm, cfg.norm_eps)
     if cfg.rope and positions is not None:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        x = apply_rope(x, positions, cfg.rope_theta)
+    return x
+
+
+def project_qkv(x: jax.Array, p: dict, cfg: ModelConfig,
+                positions: Optional[jax.Array]):
+    """x: (B, S, d) -> q (B,S,H,hd), k/v (B,S,KVH,hd); RoPE applied."""
+    b, s, _ = x.shape
+    q, k, v = _project(x, p, cfg)
+    q = _heads(q, cfg.num_heads, p.get("q_norm"), cfg, positions)
+    k = _heads(k, cfg.num_kv_heads, p.get("k_norm"), cfg, positions)
+    v = v.reshape(b, s, cfg.num_kv_heads, cfg.resolved_head_dim)
     q = constrain(q, "act_q")
     k = constrain(k, "act_kv")
     v = constrain(v, "act_kv")
@@ -228,6 +254,75 @@ def cache_write(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
     return KVCache(k=k, v=v)
 
 
+def _prefill_cache(k: jax.Array, v: jax.Array, cache: Optional[KVCache],
+                   cfg: ModelConfig) -> KVCache:
+    """The decode cache prefill hands on: the last ``s_cache`` tokens of
+    k/v (B, S, KVH, hd), ring-aligned, or the prompt in a longer cache."""
+    b, s = k.shape[:2]
+    s_cache = cache.k.shape[1] if cache is not None else s
+    if s >= s_cache:
+        kc, vc = k[:, -s_cache:], v[:, -s_cache:]
+        # ring alignment: slot of token t is t % s_cache
+        shift = jnp.mod(s - s_cache, s_cache)
+        return KVCache(jnp.roll(kc, shift=shift, axis=1),
+                       jnp.roll(vc, shift=shift, axis=1))
+    base = cache if cache is not None else make_kv_cache(
+        b, s_cache, cfg.num_kv_heads, cfg.resolved_head_dim, k.dtype)
+    return KVCache(
+        jax.lax.dynamic_update_slice_in_dim(base.k, k.astype(base.k.dtype),
+                                            0, axis=1),
+        jax.lax.dynamic_update_slice_in_dim(base.v, v.astype(base.v.dtype),
+                                            0, axis=1))
+
+
+# --------------------------------------------------------------------------
+# Path choice
+# --------------------------------------------------------------------------
+
+def _backend() -> str:
+    return jax.default_backend()
+
+
+def attn_path(cfg: ModelConfig, mode: str, seq_len: int,
+              platform: str) -> str:
+    """The path ``attn_forward`` takes: the fused prefill kernel for a
+    prefill on one TPU (no sharding rules installed) whose prompt fits its
+    single VMEM block; ``flash_attn`` otherwise (train, whose kernel has no
+    VJP; longer prompts; other platforms)."""
+    if (mode == "prefill" and platform == "tpu"
+            and get_sharding_rules() is None
+            and prefill_kernel.fits(seq_len, cfg.num_heads,
+                                    cfg.num_kv_heads,
+                                    cfg.resolved_head_dim)):
+        return KERNEL_PATH
+    return FLASH_PATH
+
+
+@contextlib.contextmanager
+def count_attn_paths():
+    """Count, by path, the self-attention sub-blocks this thread traces
+    while the block is open; yields the ``collections.Counter``.  A traced
+    layer scan counts its body once."""
+    counts = collections.Counter()
+    outer = getattr(_paths, "counts", None)
+    _paths.counts = counts
+    try:
+        yield counts
+    finally:
+        _paths.counts = outer
+        if outer is not None:
+            outer.update(counts)
+
+
+def _rope_tables(positions: jax.Array, seq_len: int, hd: int, theta: float):
+    """cos and sin (Bp, S, hd) f32 of ``apply_rope``'s angles, each half of
+    the head dim repeating the frequencies."""
+    angles = (positions.reshape(-1, seq_len)[..., None].astype(jnp.float32)
+              * rope_frequencies(hd, theta))
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
 # --------------------------------------------------------------------------
 # Full attention sub-block (projection + attend + output)
 # --------------------------------------------------------------------------
@@ -248,34 +343,50 @@ def attn_forward(x: jax.Array, p: dict, cfg: ModelConfig, *,
     """
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
-    q, k, v = project_qkv(x, p, cfg, positions)
+    path = attn_path(cfg, mode, s, _backend())
+    counts = getattr(_paths, "counts", None)
+    if counts is not None:
+        counts[path] += 1
     new_cache = None
-    if mode == "decode":
+    if path == KERNEL_PATH:
+        qf, kf, vf = _project(x, p, cfg)
+        # the cache's k is normed and rotated as the flash path has it;
+        # a caller that drops the cache drops this too
+        k = _heads(kf, cfg.num_kv_heads, p.get("k_norm"), cfg, positions)
+        v = vf.reshape(b, s, cfg.num_kv_heads, hd)
+        new_cache = _prefill_cache(k, v, cache, cfg)
+
+        def fused(qf, kf, vf):
+            cos = sin = None
+            if cfg.rope and positions is not None:
+                cos, sin = _rope_tables(positions, s, hd, cfg.rope_theta)
+            return prefill_kernel.prefill_attention(
+                qf, kf, vf, num_heads=cfg.num_heads,
+                num_kv_heads=cfg.num_kv_heads, q_scale=p.get("q_norm"),
+                k_scale=p.get("k_norm"), eps=cfg.norm_eps, cos=cos, sin=sin,
+                causal=cfg.causal, window=cfg.sliding_window,
+                interpret=False)
+
+        def unfused(qf, kf, vf):
+            # a TPU process may still compile this step for its host CPU
+            q = _heads(qf, cfg.num_heads, p.get("q_norm"), cfg, positions)
+            out = flash_attn(q, k, v, causal=cfg.causal,
+                             window=cfg.sliding_window)
+            return out.reshape(qf.shape)
+
+        out = jax.lax.platform_dependent(qf, kf, vf, tpu=fused,
+                                         default=unfused)
+    elif mode == "decode":
+        q, k, v = project_qkv(x, p, cfg, positions)
         assert cache is not None and pos is not None
         new_cache = cache_write(cache, k, v, pos)
         out = decode_attn(q, new_cache, pos + 1)
     else:
-        win = cfg.sliding_window
-        out = flash_attn(q, k, v, causal=cfg.causal, window=win)
+        q, k, v = project_qkv(x, p, cfg, positions)
+        out = flash_attn(q, k, v, causal=cfg.causal,
+                         window=cfg.sliding_window)
         if mode == "prefill":
-            # build the decode cache: last `s_cache` tokens, ring-aligned
-            s_cache = cache.k.shape[1] if cache is not None else s
-            kc, vc = k, v
-            if s >= s_cache:
-                kc, vc = k[:, -s_cache:], v[:, -s_cache:]
-                # ring alignment: slot of token t is t % s_cache
-                shift = jnp.mod(s - s_cache, s_cache)
-                kc = jnp.roll(kc, shift=shift, axis=1)
-                vc = jnp.roll(vc, shift=shift, axis=1)
-                new_cache = KVCache(kc, vc)
-            else:
-                base = cache if cache is not None else make_kv_cache(
-                    b, s_cache, cfg.num_kv_heads, hd, x.dtype)
-                kfull = jax.lax.dynamic_update_slice_in_dim(
-                    base.k, kc.astype(base.k.dtype), 0, axis=1)
-                vfull = jax.lax.dynamic_update_slice_in_dim(
-                    base.v, vc.astype(base.v.dtype), 0, axis=1)
-                new_cache = KVCache(kfull, vfull)
+            new_cache = _prefill_cache(k, v, cache, cfg)
     out = constrain(out, "act_attn_out")
     out = out.reshape(b, s, cfg.num_heads * hd)
     out = jnp.einsum("bsk,kd->bsd", out, p["wo"])

@@ -81,6 +81,7 @@ from repro.core.exec import (BatchingPolicy, ExecCore, ReadyBatch,
 from repro.core.qos import QoSTracker
 from repro.core.types import RTX_2080TI, Allocation, ServiceGraph
 from repro.models import init_params, serve_prefill
+from repro.models.attention import count_attn_paths
 from repro.serving.transport import SHM, PayloadRef
 from repro.serving.workers import WorkerPool, WorkerSupervisor, stage_blob
 
@@ -115,6 +116,9 @@ class ModelStageServer:
         self.cfg: ModelConfig = get_config(arch, reduced=reduced)
         self.seq_len = seq_len
         self.params = init_params(jax.random.PRNGKey(seed), self.cfg)
+        # the attention path of the step (``attention.attn_path``), known
+        # once a warm-up has traced it; None for a model without attention
+        self.attn_path: Optional[str] = None
         cfg = self.cfg
 
         def run(params, tokens):
@@ -141,8 +145,13 @@ class ModelStageServer:
                  self.reduced))
 
     def warmup(self, batch: int):
+        """Compile and run the step at ``batch``.  A warm-up that traces
+        the step records the attention path it took in ``attn_path``."""
         t = jnp.zeros((batch, self.seq_len), jnp.int32)
-        self._run(self.params, t).block_until_ready()
+        with count_attn_paths() as paths:
+            self._run(self.params, t).block_until_ready()
+        if paths:
+            self.attn_path = "+".join(sorted(paths))
 
     def process(self, tokens: jax.Array) -> jax.Array:
         # from the dispatch of the step program to its result being ready:

@@ -13,6 +13,7 @@ The topology is described inside a module-scoped fixture, so only the
 worker that runs this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,32 @@ def _spec(sharding, shape, dtype=jnp.float32):
 
 def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture
+def tpu_step(monkeypatch):
+    """Trace model code as on the chip: it asks ``jax.default_backend()``
+    for its attention path, which sees the CPU here."""
+    from repro.models import attention
+    monkeypatch.setattr(attention, "_backend", lambda: "tpu")
+
+
+def _compiled_step(sharding, arch, batch, seq):
+    """The stage step as ``ModelStageServer`` jits it, compiled for the
+    described chip; returns it with its config and parameter shapes."""
+    from repro.models import init_params, serve_prefill
+    cfg = get_config(arch)
+    params = jax.tree.map(
+        lambda x: _spec(sharding, x.shape, x.dtype),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+
+    def run(p, tokens):
+        logits, _ = serve_prefill(p, tokens, cfg)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    compiled = jax.jit(run).lower(
+        params, _spec(sharding, (batch, seq), jnp.int32)).compile()
+    return compiled, cfg, params
 
 
 def test_flash_attention_compiles_at_qwen3_width(one_chip):
@@ -99,21 +126,10 @@ def test_mlstm_chunk_compiles_at_xlstm_width(one_chip):
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "qwen1.5-0.5b"])
-def test_full_width_prefill_step_fits_one_chip(one_chip, arch):
+def test_full_width_prefill_step_fits_one_chip(one_chip, tpu_step, arch):
     """The img-to-img stages' step, as ``ModelStageServer`` jits it, at
     the served batch and sequence length of ``chip_smoke.py``."""
-    from repro.models import init_params, serve_prefill
-    cfg = get_config(arch)
-    params = jax.tree.map(
-        lambda x: _spec(one_chip, x.shape, x.dtype),
-        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
-
-    def run(p, tokens):
-        logits, _ = serve_prefill(p, tokens, cfg)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    compiled = jax.jit(run).lower(
-        params, _spec(one_chip, (8, 128), jnp.int32)).compile()
+    compiled, cfg, params = _compiled_step(one_chip, arch, 8, 128)
     ma = compiled.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
@@ -121,3 +137,22 @@ def test_full_width_prefill_step_fits_one_chip(one_chip, arch):
                       for x in jax.tree.leaves(params))
     assert ma.argument_size_in_bytes >= param_bytes
     assert total < V5E_HBM_BYTES, total
+
+
+@pytest.mark.parametrize("arch,seq", [("qwen3-0.6b", 96),
+                                      ("qwen1.5-0.5b", 48)])
+def test_served_prefill_step_runs_the_fused_attention_kernel(
+        one_chip, tpu_step, arch, seq):
+    """The step of each img-to-img stage at the benchmark's prompt length
+    calls the fused prefill attention kernel, and XLA no longer copies the
+    layer's ``wq``/``wk``/``wv`` slice into a head-major layout for it."""
+    compiled, cfg, _ = _compiled_step(one_chip, arch, 8, seq)
+    text = compiled.as_text()
+    kernels = [l for l in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in l]
+    assert kernels and all("prefill_attention" in l for l in kernels)
+    d, hd = cfg.d_model, cfg.resolved_head_dim
+    for width in (cfg.num_heads * hd, cfg.num_kv_heads * hd):
+        weight_copy = re.compile(
+            rf"= bf16\[(1,)?{d},{width}\]\{{[^}}]*\}} copy\(")
+        assert not weight_copy.search(text), (arch, width)

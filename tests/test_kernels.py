@@ -202,3 +202,62 @@ def test_mlstm_xla_path_matches_ref():
     h_x, *_ = ops.mlstm_chunk(q, k, v, i_raw, f_raw, c, n, m, impl="xla")
     h_r, *_ = ops.mlstm_chunk(q, k, v, i_raw, f_raw, c, n, m, impl="ref")
     _cmp(h_x, h_r, "xla vs ref", atol=2e-3, rtol=2e-2)
+
+
+# --------------------------------------------------------------------------
+# fused prefill attention (qk-norm + RoPE + grouped causal attention)
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize(
+    "b,s,h,kvh,hd,qk_norm,bias,rope,window,row_positions", [
+        (2, 96, 16, 8, 128, True, False, True, None, False),   # qwen3-0.6b
+        (2, 48, 16, 16, 64, False, True, True, None, False),   # qwen1.5-0.5b
+        (2, 48, 4, 2, 64, True, False, True, None, True),      # grouped, hd 64
+        (2, 64, 4, 2, 128, False, False, False, None, False),  # rope off
+        (2, 96, 4, 4, 64, False, True, True, 24, False),       # window < S
+        (1, 96, 16, 8, 128, True, False, True, None, False),   # one row
+    ])
+def test_prefill_attention_matches_ref(b, s, h, kvh, hd, qk_norm, bias,
+                                       rope, window, row_positions):
+    """The fused kernel against qk-norm and RoPE as the model applies them
+    (``models.common``) followed by ``ref.attention_ref``."""
+    from repro.kernels.prefill_attention import prefill_attention
+    from repro.kernels.ref import attention_ref
+    from repro.models.attention import _rope_tables
+    from repro.models.common import apply_rope, head_rms_norm
+    ks = jax.random.split(jax.random.PRNGKey(s * h + hd), 8)
+    q = jax.random.normal(ks[0], (b, s, h * hd), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, s, kvh * hd), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, s, kvh * hd), jnp.bfloat16)
+    if bias:
+        q = q + jax.random.normal(ks[3], (h * hd,), jnp.bfloat16)
+        k = k + jax.random.normal(ks[4], (kvh * hd,), jnp.bfloat16)
+        v = v + jax.random.normal(ks[5], (kvh * hd,), jnp.bfloat16)
+    q_scale = k_scale = None
+    if qk_norm:
+        q_scale = 1 + 0.2 * jax.random.normal(ks[6], (hd,), jnp.bfloat16)
+        k_scale = 1 + 0.2 * jax.random.normal(ks[7], (hd,), jnp.bfloat16)
+    positions = jnp.arange(s, dtype=jnp.int32)[None]
+    if row_positions:
+        positions = positions + 5 * jnp.arange(b, dtype=jnp.int32)[:, None]
+    cos = sin = None
+    if rope:
+        cos, sin = _rope_tables(positions, s, hd, 1e6)
+    out = prefill_attention(q, k, v, num_heads=h, num_kv_heads=kvh,
+                            q_scale=q_scale, k_scale=k_scale, eps=1e-6,
+                            cos=cos, sin=sin, window=window, interpret=True)
+
+    def heads(x, n, scale):
+        x = x.reshape(b, s, n, hd)
+        if qk_norm:
+            x = head_rms_norm(x, scale, 1e-6)
+        if rope:
+            x = apply_rope(x, positions, 1e6)
+        return x.transpose(0, 2, 1, 3).reshape(b * n, s, hd)
+
+    vh = v.reshape(b, s, kvh, hd).transpose(0, 2, 1, 3).reshape(-1, s, hd)
+    ref = attention_ref(heads(q, h, q_scale), heads(k, kvh, k_scale), vh,
+                        num_heads=h, num_kv_heads=kvh, window=window)
+    ref = ref.reshape(b, h, s, hd).transpose(0, 2, 1, 3).reshape(b, s, -1)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    _cmp(out, ref, "prefill_attention")

@@ -117,3 +117,64 @@ def test_whisper_uses_encoder(rng_key):
     l2, _ = serve_prefill(params, tokens, cfg, frames=f2)
     assert not np.allclose(np.asarray(l1, np.float32),
                            np.asarray(l2, np.float32))
+
+
+@pytest.mark.parametrize("arch,seq,mode,platform,path", [
+    ("qwen3-0.6b", 96, "prefill", "tpu", "prefill_attention"),
+    ("qwen1.5-0.5b", 48, "prefill", "tpu", "prefill_attention"),
+    ("qwen3-0.6b", 96, "train", "tpu", "flash_attn"),
+    ("qwen3-0.6b", 1024, "prefill", "tpu", "flash_attn"),    # above bound
+    ("qwen3-0.6b", 96, "prefill", "cpu", "flash_attn"),
+    ("qwen1.5-0.5b", 48, "prefill", "cpu", "flash_attn"),
+])
+def test_attn_forward_path_choice(arch, seq, mode, platform, path,
+                                  monkeypatch):
+    """The img-to-img stages' attention at full width, traced (nothing
+    runs): the fused kernel serves prefill on a TPU; train, a prompt
+    above the kernel's single block, and the CPU take ``flash_attn``."""
+    from repro.models import attention
+    monkeypatch.setattr(attention, "_backend", lambda: platform)
+    cfg = get_config(arch)
+    p = jax.eval_shape(lambda: attention.init_attn_params(
+        jax.random.PRNGKey(0), cfg))
+    x = jax.ShapeDtypeStruct((8, seq, cfg.d_model), jnp.bfloat16)
+    positions = jnp.arange(seq, dtype=jnp.int32)[None]
+    with attention.count_attn_paths() as counts:
+        out, cache = jax.eval_shape(
+            lambda x, p: attention.attn_forward(x, p, cfg,
+                                                positions=positions,
+                                                mode=mode), x, p)
+    assert counts == {path: 1}
+    assert attention.attn_path(cfg, mode, seq, platform) == path
+    assert out.shape == x.shape
+    assert (cache is None) == (mode == "train")
+
+
+def test_stage_server_reports_its_attention_path():
+    """``attn_path`` is unknown before a warm-up traces the step, then
+    names the path; on the CPU that is ``flash_attn``."""
+    from repro.serving import ModelStageServer
+    stage = ModelStageServer("s", "qwen3-0.6b", seq_len=16)
+    assert stage.attn_path is None
+    stage.warmup(2)
+    assert stage.attn_path == "flash_attn"
+    stage.warmup(2)                    # cached: no trace, path kept
+    assert stage.attn_path == "flash_attn"
+
+
+def test_kernel_path_compiled_for_the_cpu_matches_flash(rng_key,
+                                                        monkeypatch):
+    """A step traced for a TPU but compiled for the CPU (a TPU process
+    checking itself against its host) runs ``flash_attn`` in place of the
+    kernel: logits and decode cache equal the CPU path's."""
+    from repro.models import attention
+    cfg = get_config("qwen3-0.6b", reduced=True)
+    params = init_params(rng_key, cfg)
+    tokens = jax.random.randint(rng_key, (2, 32), 0, cfg.vocab_size)
+    want = jax.jit(lambda p, t: serve_prefill(p, t, cfg))(params, tokens)
+    monkeypatch.setattr(attention, "_backend", lambda: "tpu")
+    with attention.count_attn_paths() as counts:
+        got = jax.jit(lambda p, t: serve_prefill(p, t, cfg))(params, tokens)
+    assert counts == {"prefill_attention": 1}
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
