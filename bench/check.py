@@ -13,7 +13,8 @@ Three numbers, each against its limit in the configuration file:
 * ``gap.<stage>``: for a sample of the completed queries drawn from the
   seed, the widest gap by which the token a stage served lies below the
   best logit of the float32 reference at that stage's prompt.  The
-  reference runs once over the sampled prompts of each stage.
+  reference, the family the stage names in ``bench/reference``, runs once
+  over the sampled prompts of each stage.
 
 Each stage call's input and output were kept by reference during the
 window (``harness.Recorder``); rows of a call are matched to queries by
@@ -22,6 +23,7 @@ tokens its prompt repeats.
 """
 from __future__ import annotations
 
+from pathlib import Path
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -92,9 +94,10 @@ def widest_gap(logits: np.ndarray, tokens: np.ndarray) -> float:
 
 def check(config: dict, queries, calls: List[List[tuple]],
           stage_seeds: List[int], *, completed: int, failed: int,
-          sample: int, rng: np.random.Generator) -> dict:
+          sample: int, rng: np.random.Generator, bench: Path) -> dict:
     """The verdict of one run: ``{"correct": bool, "compared": {name:
-    {"value", "limit"}}}``."""
+    {"value", "limit"}}}``.  ``bench`` is the run's own ``bench/``
+    directory, where the stages' reference families are found."""
     limits = config["limits"]
     vocab = [st["model"]["vocab_size"] for st in config["stages"]]
     done = [q for q in queries if q.done is not None]
@@ -112,8 +115,8 @@ def check(config: dict, queries, calls: List[List[tuple]],
         served = np.array([p for _, p in ok], np.int64)
         for k, (st, prompts) in enumerate(zip(
                 config["stages"], stage_prompts(config, prompts0, served))):
-            logits = reference.last_logits(st["model"], stage_seeds[k],
-                                           prompts)
+            logits = reference.last_logits(st, stage_seeds[k], prompts,
+                                           bench=bench)
             values[f"gap.{st['name']}"] = widest_gap(logits, served[:, k])
 
     compared = {}

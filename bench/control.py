@@ -47,8 +47,8 @@ def control_gaps(config: dict, seed: int, n: int = SAMPLE_QUERIES) -> dict:
     out = {}
     for k, st in enumerate(stages):
         s = derived_seed(seed, "weights", k)
-        ref = reference.last_logits(st["model"], s, prompts)
-        ctl = reference.last_logits(st["model"], s, prompts, quant="fp8")
+        ref = reference.last_logits(st, s, prompts)
+        ctl = reference.last_logits(st, s, prompts, quant="fp8")
         out[f"gap.{st['name']}"] = check.widest_gap(ref, ctl.argmax(axis=1))
         if k + 1 < len(stages):
             nxt = stages[k + 1]
@@ -63,10 +63,10 @@ def serve_control(config: dict) -> None:
     with the token that the float8 reference puts first."""
     import jax.numpy as jnp
     from repro.serving import engine
-    models = {st["name"]: st["model"] for st in config["stages"]}
+    stages = {st["name"]: st for st in config["stages"]}
 
     def control(self, tokens):
-        logits = reference.last_logits(models[self.name], self._seed,
+        logits = reference.last_logits(stages[self.name], self._seed,
                                        np.asarray(tokens), quant="fp8")
         return jnp.asarray(logits.argmax(axis=1).astype(np.int32))
     engine.ModelStageServer.process = control

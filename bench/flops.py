@@ -5,70 +5,32 @@ once and serves the argmax of the last position.  Useful work is then:
 
 * every weight matrix applied to every prompt position, 2 FLOPs per
   multiply-add (the arithmetic of ``repro.launch.roofline.analytic_costs``
-  in prefill mode);
-* the output head at the last position only;
-* attention over the causal half of the score matrix, ``S(S+1)/2`` pairs,
-  for scores and for the weighted sum of values;
-* the mLSTM memory in its parallel form, the same causal pairs per head.
+  in prefill mode): ``2 * seq_len * matmul_params``;
+* the output head at the last position only: ``2 * d_model * vocab``;
+* the sequence mixing over the causal pairs of the prompt, ``S(S+1)/2``
+  of them (attention's scores and weighted sum, a recurrent memory in its
+  parallel form): ``mixing_flops``.
 
-Rows that only pad a partial batch, the other positions' logits, the
-masked upper half of attention and the decode cache the prefill writes
-are not useful work and are not counted.  Elementwise work (norms, gates,
-the sLSTM cell update) is not counted either: it is not matrix work.
+The stage's reference family (``bench/reference/<family>.py``) gives the
+first and the last term for the block kinds it serves; this file names
+no kind.  Rows that only pad a partial batch, the other positions'
+logits, the masked half of the pairs and the decode cache the prefill
+writes are not useful work and are not counted.  Elementwise work (norms,
+gates, recurrent cell updates) is not counted either: it is not matrix
+work.
 """
 from __future__ import annotations
 
+from pathlib import Path
 
-def matmul_params(m: dict) -> int:
-    """Weights applied by matrix products to each position, all layers."""
-    d = m["d_model"]
-    total = 0
-    for kind, mlp in zip(m["block_pattern"], m["mlp_pattern"]):
-        if kind == "attn":
-            hd, h, kvh = m["head_dim"], m["num_heads"], m["num_kv_heads"]
-            total += d * h * hd + 2 * d * kvh * hd + h * hd * d
-        elif kind == "mlstm":
-            inner = m["xlstm_expand"] * d
-            nh = m["xlstm_num_heads"]
-            # up-projection, per-head q/k/v blocks, the two gates, down
-            total += (d * 2 * inner + 3 * inner * (inner // nh)
-                      + 2 * inner * nh + inner * d)
-        elif kind == "slstm":
-            nh = m["xlstm_num_heads"]
-            dffn = int(d * 4 / 3)
-            # gate input projection, per-head recurrence, GEGLU block
-            total += 4 * d * d + 4 * d * (d // nh) + 3 * d * dffn
-        else:
-            raise ValueError(f"no FLOP count for block kind {kind!r}")
-        if mlp == "dense":
-            total += 3 * d * m["d_ff"]
-        elif mlp != "none":
-            raise ValueError(f"no FLOP count for feed-forward kind {mlp!r}")
-    return total * (m["num_layers"] // len(m["block_pattern"]))
+from bench import reference
 
 
-def mixing_flops(m: dict, s: int) -> float:
-    """Attention and mLSTM memory over the causal pairs of one prompt."""
-    pairs = s * (s + 1) / 2
-    n_sb = m["num_layers"] // len(m["block_pattern"])
-    total = 0.0
-    for kind in m["block_pattern"]:
-        if kind == "attn":
-            total += 2 * 2 * pairs * m["num_heads"] * m["head_dim"]
-        elif kind == "mlstm":
-            inner = m["xlstm_expand"] * m["d_model"]
-            total += 2 * 2 * pairs * inner
-    return total * n_sb
-
-
-def stage_flops(m: dict, seq_len: int) -> float:
-    """Useful FLOPs of one query at a stage of model ``m``."""
-    return (2.0 * seq_len * matmul_params(m)
+def stage_flops(stage: dict, bench: Path = reference.BENCH) -> float:
+    """Useful FLOPs of one query at ``stage`` (a configuration file's stage
+    entry), counted by the family it names in ``bench/reference``."""
+    m, s = stage["model"], stage["seq_len"]
+    fam = reference.family(stage, bench)
+    return (2.0 * s * fam.matmul_params(m)
             + 2.0 * m["d_model"] * m["vocab_size"]
-            + mixing_flops(m, seq_len))
-
-
-def query_flops(stages) -> float:
-    """Useful FLOPs of one query through every stage (``stages`` as the
-    configuration file lists them)."""
-    return sum(stage_flops(st["model"], st["seq_len"]) for st in stages)
+            + fam.mixing_flops(m, s))

@@ -3,9 +3,11 @@
 A cell of ``BENCHMARK.json`` names a configuration (``bench/configs``), a
 traffic mix (``bench/traffic/<traffic>.json``, whose ``arrival`` names a
 generator in ``bench/arrivals``) and its own file ``bench/workloads/
-<cell>.json`` with the rate it offers.  Each metric is a reader in
-``bench/metrics/<name>.py``.  Adding a cell, a mix or a metric adds files
-and edits none.
+<cell>.json`` with the rate it offers.  Each stage of a configuration
+names its reference family, ``bench/reference/<family>.py``, which gives
+the plain reference and the useful FLOPs of its block kinds.  Each metric
+is a reader in ``bench/metrics/<name>.py``.  Adding a cell, a mix, a
+metric or a model family adds files and edits none.
 
 One run builds the service through the program's public entry (spec,
 profile, ``solve("max-peak")``, ``serve``), replays a short warm-up of the
@@ -21,14 +23,17 @@ finds nothing to read.  The record holds: ``seconds`` (the window),
 from the window's opening, NaN where it never completed), ``completed``,
 ``failed`` and ``batches`` (``ServeStats``), ``comm_time_s`` and
 ``transfers`` (the hand-off's host time and ``EdgeChannel`` transfers in
-the window), ``stages`` (each stage's ``seq_len`` and model sizes),
+the window), ``stages`` (each stage's ``seq_len``, reference family,
+model sizes and ``flops``, its useful FLOPs a query, ``bench/flops.py``),
 ``peaks`` (``peaks.json`` for the chip), and in a traced run ``trace``
 (``trace_reduce.reduce_trace``).  A metric named ``<base>.<cells>``, one
-reading split by the end-to-end metric it moves in different cells, has
-no reader of its own and is read by ``<base>.py``.
+reading split by cells (a per-layer one by the end-to-end metric it moves
+there, an end-to-end one by the cells that report it), has no reader of
+its own and is read by ``<base>.py``.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import glob
 import hashlib
@@ -41,6 +46,8 @@ from pathlib import Path
 from typing import List, Optional
 
 import numpy as np
+
+from bench.flops import stage_flops
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -106,6 +113,15 @@ class Cell:
         self.entry = cells[name]
         configs = {c["name"]: c for c in bm["configs"]}
         self.config = load_json(root / configs[self.entry["config"]]["file"])
+        # each stage as a run's record holds it; counting its useful FLOPs
+        # finds its reference family and raises where the family is missing
+        # or does not serve the stage's block kinds
+        self.stages = []
+        for st in self.config["stages"]:
+            flops = stage_flops(st, bench)
+            self.stages.append({"name": st["name"], "seq_len": st["seq_len"],
+                                "reference": st["reference"],
+                                "model": st["model"], "flops": flops})
         self.traffic = load_json(bench / "traffic"
                                  / f"{self.entry['traffic']}.json")
         self.spec = load_json(bench / "workloads" / f"{name}.json")
@@ -131,15 +147,26 @@ class Cell:
 
 def _check_stage_config(stage, want: dict) -> None:
     """The configuration file holds the model as it is run: every size it
-    states must be the program's."""
-    cfg = stage.cfg
+    states must be the program's.  A section (a dict, such as ``moe``) is
+    compared key by key with the program's dataclass of that name."""
+    _check_sizes(stage.name, stage.cfg, want, "")
+
+
+def _check_sizes(stage_name: str, cfg, want: dict, prefix: str) -> None:
     for key, value in want.items():
-        got = cfg.resolved_head_dim if key == "head_dim" else getattr(cfg,
-                                                                      key)
+        path = prefix + key
+        if not hasattr(cfg, key):
+            raise ValueError(f"stage {stage_name}: the program's config has "
+                             f"no {path}")
+        got = cfg.resolved_head_dim if path == "head_dim" else getattr(cfg,
+                                                                       key)
+        if isinstance(value, dict) and dataclasses.is_dataclass(got):
+            _check_sizes(stage_name, got, value, path + ".")
+            continue
         if isinstance(got, tuple):
             got = list(got)
         if got != value:
-            raise ValueError(f"stage {stage.name}: {key} is {got!r} in the "
+            raise ValueError(f"stage {stage_name}: {path} is {got!r} in the "
                              f"program, {value!r} in the configuration file")
 
 
@@ -448,8 +475,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         "batches": int(stats.batches),
         "comm_time_s": float(stats.comm_time),
         "transfers": int(transfers1 - transfers0),
-        "stages": [{"name": st["name"], "seq_len": st["seq_len"],
-                    "model": st["model"]} for st in config["stages"]],
+        "stages": cell.stages,
         "peaks": peaks_for(device_kind, cell.bench),
         "trace": None,
     }
@@ -494,7 +520,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
                           completed=record["completed"],
                           failed=record["failed"], sample=SAMPLE_QUERIES,
                           rng=np.random.default_rng(
-                              derived_seed(seed, "sample")))
+                              derived_seed(seed, "sample")),
+                          bench=cell.bench)
     log(f"[bench] check took {time.perf_counter() - t0:.3f} s")
 
     metrics = {}

@@ -1,6 +1,7 @@
 """Stage step, whole-step share of the chip's bf16 peak, in percent: the
-useful FLOPs (``bench/flops.py``) of the step programs' executions in the
-profiler trace over their device time in that trace times the peak.
+useful FLOPs (each stage's ``flops`` in the record, counted at cell load by
+``bench/flops.py``) of the step programs' executions in the profiler trace
+over their device time in that trace times the peak.
 
 A stage's step takes its prompts as ``s32[batch, seq_len]``, so a step
 program whose operations touch that shape, and no other stage's, serves
@@ -12,7 +13,6 @@ chain whole.  A step program that serves no stage, or more than one, is
 left out of both sides.  ``mfu_pct.over`` reads the same number in the
 over-capacity cell, where it moves ``served_qps``.
 """
-from bench.flops import stage_flops
 
 
 def read(record):
@@ -30,8 +30,7 @@ def read(record):
         if len(stages) != 1:
             continue
         ((rows, st),) = stages.values()
-        flops += (prog["count"] * rows * fill
-                  * stage_flops(st["model"], st["seq_len"]))
+        flops += prog["count"] * rows * fill * st["flops"]
         seconds += prog["seconds"]
     if not seconds:
         return None

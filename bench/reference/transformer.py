@@ -7,6 +7,10 @@ RMSNorm of each query and key head (Qwen3); then RMSNorm and a SwiGLU
 feed-forward block.  A final RMSNorm of the last position and the output
 head (the embedding, transposed, where tied) give the last-token logits.
 Attention is computed whole, causal mask and softmax in float32.
+
+The family serves attention blocks each with a dense feed-forward block,
+and gives their useful FLOPs (``bench/flops.py``): the q/k/v/o and SwiGLU
+weights at every position, and attention over the causal pairs.
 """
 from __future__ import annotations
 
@@ -18,6 +22,38 @@ import numpy as np
 
 from bench.reference import weights as W
 from bench.reference.ops import mm, rms_norm, silu
+
+
+def _served(m):
+    for kind, mlp in zip(m["block_pattern"], m["mlp_pattern"]):
+        if (kind, mlp) != ("attn", "dense"):
+            raise ValueError(f"the transformer reference serves attention "
+                             f"blocks with a dense feed-forward block, not "
+                             f"{kind!r} with {mlp!r}")
+
+
+def matmul_params(m: dict) -> int:
+    """Weights applied by matrix products to each position, all layers."""
+    _served(m)
+    d, hd = m["d_model"], m["head_dim"]
+    h, kvh = m["num_heads"], m["num_kv_heads"]
+    total = 0
+    for _ in m["block_pattern"]:
+        # q, k, v, o; then the SwiGLU block's gate, up and down
+        total += d * h * hd + 2 * d * kvh * hd + h * hd * d
+        total += 3 * d * m["d_ff"]
+    return total * (m["num_layers"] // len(m["block_pattern"]))
+
+
+def mixing_flops(m: dict, s: int) -> float:
+    """Attention over the causal pairs of one prompt: scores and the
+    weighted sum of values."""
+    pairs = s * (s + 1) / 2
+    n_sb = m["num_layers"] // len(m["block_pattern"])
+    total = 0.0
+    for _ in m["block_pattern"]:
+        total += 2 * 2 * pairs * m["num_heads"] * m["head_dim"]
+    return total * n_sb
 
 
 def _attn_shapes(m):
@@ -85,6 +121,7 @@ def _head(h_last, table, mkey, quant):
 
 def last_logits(m: dict, seed: int, prompts: np.ndarray, quant=None):
     """Last-position logits (B, V) of ``prompts`` (B, S) int32."""
+    _served(m)
     mkey = tuple(sorted((k, tuple(v) if isinstance(v, list) else v)
                         for k, v in m.items()))
     keys = W.root_keys(seed)

@@ -28,6 +28,11 @@ split into the gate chunks.  Exponential input gate with the paper's
 log-space stabiliser ``m``, normaliser floored at ``exp(-m)``, stepped
 sequentially over the prompt from a zero state; then RMSNorm and a GEGLU
 feed-forward block (tanh-approximated GELU, width ``int(4/3 * d)``).
+
+The family serves mLSTM and sLSTM blocks without a feed-forward block of
+their own, and gives their useful FLOPs (``bench/flops.py``): each
+block's projections at every position, and the mLSTM memory over the
+causal pairs in its parallel form.
 """
 from __future__ import annotations
 
@@ -40,6 +45,47 @@ import numpy as np
 from bench.reference import weights as W
 from bench.reference.ops import (gelu_tanh, log_sigmoid, mm, rms_norm,
                                  sigmoid, silu)
+
+
+def _served(m):
+    for kind, mlp in zip(m["block_pattern"], m["mlp_pattern"]):
+        if kind not in ("mlstm", "slstm") or mlp != "none":
+            raise ValueError(f"the xlstm reference serves mLSTM and sLSTM "
+                             f"blocks without a feed-forward block, not "
+                             f"{kind!r} with {mlp!r}")
+
+
+def matmul_params(m: dict) -> int:
+    """Weights applied by matrix products to each position, all layers."""
+    _served(m)
+    d = m["d_model"]
+    total = 0
+    for kind in m["block_pattern"]:
+        if kind == "mlstm":
+            inner = m["xlstm_expand"] * d
+            nh = m["xlstm_num_heads"]
+            # up-projection, per-head q/k/v blocks, the two gates, down
+            total += (d * 2 * inner + 3 * inner * (inner // nh)
+                      + 2 * inner * nh + inner * d)
+        else:
+            nh = m["xlstm_num_heads"]
+            dffn = int(d * 4 / 3)
+            # gate input projection, per-head recurrence, GEGLU block
+            total += 4 * d * d + 4 * d * (d // nh) + 3 * d * dffn
+    return total * (m["num_layers"] // len(m["block_pattern"]))
+
+
+def mixing_flops(m: dict, s: int) -> float:
+    """The mLSTM memory over the causal pairs of one prompt, in its
+    parallel form; the sLSTM's recurrence is elementwise."""
+    pairs = s * (s + 1) / 2
+    n_sb = m["num_layers"] // len(m["block_pattern"])
+    total = 0.0
+    for kind in m["block_pattern"]:
+        if kind == "mlstm":
+            inner = m["xlstm_expand"] * m["d_model"]
+            total += 2 * 2 * pairs * inner
+    return total * n_sb
 
 
 def _dims(m):
@@ -153,6 +199,7 @@ def _head(h_last, table, mkey, quant):
 
 def last_logits(m: dict, seed: int, prompts: np.ndarray, quant=None):
     """Last-position logits (B, V) of ``prompts`` (B, S) int32."""
+    _served(m)
     mkey = tuple(sorted((k, tuple(v) if isinstance(v, list) else v)
                         for k, v in m.items()))
     pattern = m["block_pattern"]

@@ -4,10 +4,19 @@ import jax
 import numpy as np
 import pytest
 
-from bench.flops import matmul_params, stage_flops
-from bench.harness import (BENCH, derived_seed, load_json, load_module,
-                           metric_reader, peaks_for)
+from bench import reference
+from bench.flops import stage_flops
+from bench.harness import (BENCH, Cell, derived_seed, load_json,
+                           load_module, metric_reader, peaks_for)
 from bench.tests.tiny import config_file
+
+# useful FLOPs of one query at each stage, as the counts gave them when
+# they still sat in bench/flops.py: moving them into the families keeps
+# every number that mfu_pct reads
+USEFUL_FLOPS = {"face-recognition": 85_936_308_224,
+                "image-enhancement": 30_021_779_456,
+                "semantic-understanding": 116_620_001_280,
+                "image-generation": 80_042_786_816}
 
 
 def test_peaks_lookup_by_device_kind():
@@ -44,7 +53,35 @@ def test_matmul_params_match_the_program_weights(stage):
             name = jax.tree_util.keystr(path)
             if leaf.ndim >= 3 and "conv_w" not in name:
                 total += int(np.prod(leaf.shape))
-    assert matmul_params(stage["model"]) == total
+    assert reference.family(stage).matmul_params(stage["model"]) == total
+
+
+@pytest.mark.parametrize("stage", list(_stage_models()),
+                         ids=lambda st: st["name"])
+def test_stage_flops_unchanged(stage):
+    assert stage_flops(stage) == USEFUL_FLOPS[stage["name"]]
+
+
+def test_family_refuses_a_kind_it_does_not_serve():
+    """An attention model with sparse experts is not the transformer
+    family's dense model: its count and its reference both refuse it."""
+    st = next(_stage_models())
+    moe = {**st, "model": {**st["model"], "mlp_pattern": ["moe"]}}
+    with pytest.raises(ValueError, match="'moe'"):
+        stage_flops(moe)
+    with pytest.raises(ValueError, match="'moe'"):
+        reference.last_logits(moe, 0, np.zeros((1, 4), np.int32))
+
+
+@pytest.mark.parametrize("path", [BENCH / "flops.py",
+                                  BENCH / "reference" / "__init__.py"],
+                         ids=lambda p: p.name)
+def test_dispatch_names_no_block_kind(path):
+    """Every count and every reference lives in a family module found by
+    the stage's name for it: the dispatch lists no kind."""
+    text = path.read_text()
+    for kind in ("attn", "mlstm", "slstm", "dense", "moe", "none"):
+        assert f'"{kind}"' not in text and f"'{kind}'" not in text, kind
 
 
 @pytest.mark.parametrize("arch,seq", [("qwen3-0.6b", 96),
@@ -61,19 +98,19 @@ def test_stage_flops_against_analytic_costs(arch, seq):
     shp = InputShape(name="bench", kind="prefill", seq_len=seq,
                      global_batch=1)
     full = analytic_costs(cfg, shp)["flops"]
-    m = next(st["model"] for st in _stage_models() if st["arch"] == arch)
+    st = next(st for st in _stage_models() if st["arch"] == arch)
+    m = st["model"]
     hd, nh = m["head_dim"], m["num_heads"]
     masked = 4.0 * nh * hd * (seq * seq - seq * (seq + 1) / 2) \
         * m["num_layers"]
     head = 2.0 * (seq - 1) * m["d_model"] * m["vocab_size"]
-    assert stage_flops(m, seq) == pytest.approx(full - head - masked,
-                                                rel=1e-12)
+    assert stage_flops({**st, "seq_len": seq}) == pytest.approx(
+        full - head - masked, rel=1e-12)
 
 
 def test_img_to_img_query_flops_near_the_weight_estimate():
     cfg = load_json(BENCH / "configs" / "img-to-img.json")
-    q3, q15 = (stage_flops(st["model"], st["seq_len"])
-               for st in cfg["stages"])
+    q3, q15 = (stage_flops(st) for st in cfg["stages"])
     # 2 x matrix weights x tokens: 84.5 and 29.6 GFLOP, plus head and
     # attention
     assert 84.5e9 < q3 < 87e9
@@ -106,8 +143,7 @@ def _mfu_record(programs, completed=800, batches=100):
     cfg = load_json(BENCH / "configs" / "img-to-img.json")
     return {"trace": {"programs": programs}, "batch": 8,
             "completed": completed, "batches": batches,
-            "stages": [{"name": st["name"], "seq_len": st["seq_len"],
-                        "model": st["model"]} for st in cfg["stages"]],
+            "stages": Cell("img-to-img.poisson-knee80").stages,
             "peaks": peaks_for("TPU v5 lite")}, cfg["stages"]
 
 
@@ -123,8 +159,7 @@ def test_mfu_counts_each_step_program_as_its_stage():
                 "jit_run(3)": {"count": 5, "seconds": 9.0,
                                "shapes": [[8, 1]]}}
     record, stages = _mfu_record(programs)
-    flops = 100 * 8 * sum(stage_flops(st["model"], st["seq_len"])
-                          for st in stages)
+    flops = 100 * 8 * sum(stage_flops(st) for st in stages)
     assert read(record) == pytest.approx(100 * flops / (0.9 * 197e12))
     half, _ = _mfu_record(programs, completed=400)
     assert read(half) == pytest.approx(read(record) / 2)

@@ -1,11 +1,13 @@
 """A copy of the benchmark in a temporary directory, with one more
 configuration at the laptop-scale widths, its own traffic mix, cell and
-metric: every file new, no file of the benchmark edited."""
+metric, and optionally a reference family of its own: every file new, no
+file of the benchmark edited."""
 from __future__ import annotations
 
 import json
 import shutil
 from pathlib import Path
+from typing import Optional
 
 from repro.configs import get_config
 
@@ -36,10 +38,13 @@ def config_file(name: str) -> Path:
     return own if own.exists() else DATA / f"{name}.json"
 
 
-def make_root(tmp: Path, base: str = "img-to-img"):
+def make_root(tmp: Path, base: str = "img-to-img",
+              family: Optional[str] = None):
     """``tmp/checkout`` holding ``BENCHMARK.json`` and ``bench/`` as
     committed, plus the tiny configuration of ``base``'s service and its
-    cell.  Returns the root and the cell's name."""
+    cell.  With ``family``, the checkout also holds a copy of the
+    ``transformer`` reference under that name, and the tiny configuration's
+    transformer stages name it.  Returns the root and the cell's name."""
     cell = f"{base}-tiny.poisson-tiny"
     root = tmp / "checkout"
     shutil.copytree(ROOT / "bench", root / "bench",
@@ -50,6 +55,11 @@ def make_root(tmp: Path, base: str = "img-to-img"):
     config["name"] = f"{base}-tiny"
     for st in config["stages"]:
         st["model"] = _reduced_model(st["arch"], st["model"])
+        if family and st["reference"] == "transformer":
+            st["reference"] = family
+    if family:
+        shutil.copy(ROOT / "bench" / "reference" / "transformer.py",
+                    root / "bench" / "reference" / f"{family}.py")
     config["limits"] = {"lost": 0, "misrouted": 0}
     for st in config["stages"]:
         config["limits"][f"gap.{st['name']}"] = TINY_GAP_LIMITS[st["arch"]]
